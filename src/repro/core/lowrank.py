@@ -488,8 +488,11 @@ def make_lowrank_optimizer(
                     # M (old_r, n) -> (new_r, n)
                     m2 = jnp.einsum("...no,...ok->...nk", c, m)
                 else:
-                    # M (m, old_r) -> (m, new_r)
-                    m2 = jnp.einsum("...ko,...no->...kn", m, c)
+                    # M (m, old_r) -> (m, new_r), as (C M^T)^T: the
+                    # canonical operand order (see projectors.project)
+                    m2 = jnp.swapaxes(jnp.einsum(
+                        "...no,...ok->...nk", c, jnp.swapaxes(m, -1, -2)
+                    ), -1, -2)
                 inner_state = inner_state._replace(m=m2.astype(m.dtype))
         return LeafState(projector=new_p, inner=inner_state), overlap
 

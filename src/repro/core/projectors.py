@@ -104,18 +104,30 @@ def projector_dim(shape) -> int:
     return min(shape[-2], shape[-1])
 
 
+def _t(x: jax.Array) -> jax.Array:
+    return jnp.swapaxes(x, -1, -2)
+
+
+# side='right' products are computed as the transpose of the side='left'
+# product of the transposed operands -- the operand order of the bucketed
+# engine's canonical (transposed) stacks.  XLA does not promise that
+# A B and (B^T A^T)^T round alike (CPU dot kernels differ by an ulp between
+# the two), so this is what keeps the per-leaf reference and the bucketed
+# engine bit-for-bit on every backend.
+
+
 def project(g: jax.Array, p: jax.Array, side: str) -> jax.Array:
     """R = P^T G (left) or G P (right); batched over leading dims."""
     if side == "left":
         return jnp.einsum("...dr,...dn->...rn", p, g)
-    return jnp.einsum("...md,...dr->...mr", g, p)
+    return _t(jnp.einsum("...dr,...dn->...rn", p, _t(g)))
 
 
 def backproject(d: jax.Array, p: jax.Array, side: str) -> jax.Array:
     """Full-space update from projected direction."""
     if side == "left":
         return jnp.einsum("...dr,...rn->...dn", p, d)
-    return jnp.einsum("...mr,...dr->...md", d, p)
+    return _t(jnp.einsum("...dr,...rn->...dn", p, _t(d)))
 
 
 def residual(g: jax.Array, p: jax.Array, side: str) -> jax.Array:

@@ -1,8 +1,8 @@
-"""Version-compat shims + shared plumbing for the Pallas TPU kernels.
+"""Shared plumbing for the Pallas TPU kernels.
 
-``CompilerParams``: jax renamed ``pltpu.TPUCompilerParams`` to
-``pltpu.CompilerParams``; the kernels were written against the new name.
-Import it from here so both jax generations work.
+``CompilerParams``: the Mosaic compiler-parameter type every kernel passes
+to ``pallas_call`` (``dimension_semantics`` etc.), re-exported so the
+kernels name it in one place.
 
 ``pick_block``: safe block-size selection for non-divisible dims.  The old
 per-kernel fallback (``bd, bn = d, n`` whenever a dim wasn't divisible by the
@@ -11,14 +11,12 @@ the ragged test shapes it was written for, a VMEM blow-up for production
 shapes like d_ff=11008 with block 512 (11008 % 512 != 0 -> a 4096 x 11008
 f32 block is ~180 MB against ~16 MB of VMEM).  ``pick_block`` instead rounds
 down to the largest *divisor* of the dim that is a multiple of ``align``
-(TPU lane width), then to any divisor, and only then falls back to the whole
-dim (small ragged shapes where that is the right answer).
+(TPU lane width), and only then falls back to the whole dim (small ragged
+shapes where that is the right answer).
 """
 from __future__ import annotations
 
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from jax.experimental.pallas.tpu import CompilerParams  # noqa: F401
 
 
 def pick_block(dim: int, block: int, align: int = 128) -> int:

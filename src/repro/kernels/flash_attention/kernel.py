@@ -2,9 +2,11 @@
 
 Grid: (B, H, nq, nk) with the KV dimension innermost ("arbitrary" semantics:
 sequential on-core so the (m, l, acc) scratch carries across KV blocks of one
-query block).  Block shapes keep D (=head_dim) whole in lanes and the q/kv
-block sizes as sublane multiples -- q_blk x D and kv_blk x D tiles feed the
-MXU directly.
+query block).  The kernel runs on a heads-major (B, H, S, D) view -- the
+wrapper transposes the models' (B, S, H, D) layout on the way in and out --
+so every block's last two dims are (seq block, D): D (=head_dim) whole in
+lanes and the q/kv block sizes as sublane multiples, which is the tiling
+Mosaic requires; q_blk x D and kv_blk x D tiles feed the MXU directly.
 
 Causal skipping: fully-masked KV blocks are skipped with ``pl.when`` (no MXU
 work issued); the diagonal block applies the elementwise mask from absolute
@@ -14,8 +16,7 @@ GQA is expressed through the K/V index_map (kv_head = q_head // group), so K/V
 blocks are fetched once per query-head group rather than replicated in HBM.
 
 Backward: registered as a custom_vjp whose backward recomputes attention via
-the jnp reference (flash-bwd kernel is future work -- on the training path
-the chunked-jnp attention is used instead; see models/attention.py).
+the jnp reference (a flash-bwd kernel is future work).
 """
 from __future__ import annotations
 
@@ -33,10 +34,10 @@ NEG = -1e30
 
 
 def _fwd_kernel(
-    q_ref,  # (1, bq, 1, D)
-    k_ref,  # (1, bk, 1, D)
-    v_ref,  # (1, bk, 1, D)
-    o_ref,  # (1, bq, 1, D)
+    q_ref,  # (1, 1, bq, D)
+    k_ref,  # (1, 1, bk, D)
+    v_ref,  # (1, 1, bk, D)
+    o_ref,  # (1, 1, bq, D)
     m_scr,  # (bq, 128) f32  (broadcast lanes)
     l_scr,  # (bq, 128) f32
     acc_scr,  # (bq, D) f32
@@ -72,9 +73,9 @@ def _fwd_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -105,7 +106,7 @@ def _fwd_kernel(
     def _finalize():
         l = l_scr[:, :1]
         out = acc_scr[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -140,22 +141,23 @@ def flash_attention_fwd(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         q_offset=q_offset, bq=bq, bk=bk, nk=nk,
     )
-    return pl.pallas_call(
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)  # (B, S, H, D) <-> (B, H, S, D)
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
+            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec(
-                (1, bk, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih // g, 0)
+                (1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // g, ik, 0)
             ),
             pl.BlockSpec(
-                (1, bk, 1, d), lambda ib, ih, iq, ik: (ib, ik, ih // g, 0)
+                (1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // g, ik, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, bq, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)
+            (1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -166,7 +168,8 @@ def flash_attention_fwd(
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(heads_major(q), heads_major(k), heads_major(v))
+    return heads_major(out)
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,12 @@ sliding window.  Empty slots (``seq_len == 0``) produce zeros, not NaN.
 GQA is expressed through the K/V index maps (kv head = q head // group),
 matching the training kernel in ``kernels/flash_attention``.
 
+The kernel reads the pool as (P, ps, KVH*D) and the query / output as
+(B, 1, H*D) -- free reshapes of the (.., KVH, D) / (.., H, D) layouts that
+merge the head and head-dim axes -- so each block's last two dims are
+(ps, D) or (1, D) with D lane-aligned, the tiling Mosaic requires; a head
+is a D-wide lane block.
+
 VMEM budget per program: one (ps, D) K tile + one (ps, D) V tile + the
 (1, 128)/(1, D) f32 scratch -- a few KB at ps=16..64, far below the ~16 MB
 core budget, leaving the pipeline free to double-buffer page DMAs.
@@ -42,10 +48,10 @@ NEG = -1e30
 def _decode_kernel(
     pt_ref,  # (B*MP,) int32 scalar-prefetch page table (flattened)
     sl_ref,  # (B,) int32 scalar-prefetch seq lens
-    q_ref,  # (1, 1, 1, D)
-    k_ref,  # (1, ps, 1, D)
-    v_ref,  # (1, ps, 1, D)
-    o_ref,  # (1, 1, 1, D)
+    q_ref,  # (1, 1, D)
+    k_ref,  # (1, ps, D)
+    v_ref,  # (1, ps, D)
+    o_ref,  # (1, 1, D)
     m_scr,  # (1, 128) f32
     l_scr,  # (1, 128) f32
     acc_scr,  # (1, D) f32
@@ -72,9 +78,9 @@ def _decode_kernel(
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32)[None, :]  # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)  # (1, D)
+        k = k_ref[0].astype(jnp.float32)  # (ps, D)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -102,7 +108,7 @@ def _decode_kernel(
     def _finalize():
         l = l_scr[:, :1]
         out = acc_scr[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, 0, 0, :] = out[0].astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -136,24 +142,22 @@ def paged_decode_attention_kernel(
         num_scalar_prefetch=2,
         grid=(b, h, mp),
         in_specs=[
+            pl.BlockSpec((1, 1, d), lambda ib, ih, ik, pt, sl: (ib, 0, ih)),
             pl.BlockSpec(
-                (1, 1, 1, d), lambda ib, ih, ik, pt, sl: (ib, 0, ih, 0)
-            ),
-            pl.BlockSpec(
-                (1, ps, 1, d),
+                (1, ps, d),
                 lambda ib, ih, ik, pt, sl: (
-                    jnp.maximum(pt[ib * mp + ik], 0), 0, ih // g, 0
+                    jnp.maximum(pt[ib * mp + ik], 0), 0, ih // g
                 ),
             ),
             pl.BlockSpec(
-                (1, ps, 1, d),
+                (1, ps, d),
                 lambda ib, ih, ik, pt, sl: (
-                    jnp.maximum(pt[ib * mp + ik], 0), 0, ih // g, 0
+                    jnp.maximum(pt[ib * mp + ik], 0), 0, ih // g
                 ),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, 1, d), lambda ib, ih, ik, pt, sl: (ib, 0, ih, 0)
+            (1, 1, d), lambda ib, ih, ik, pt, sl: (ib, 0, ih)
         ),
         scratch_shapes=[
             pltpu.VMEM((1, 128), jnp.float32),
@@ -161,10 +165,10 @@ def paged_decode_attention_kernel(
             pltpu.VMEM((1, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -172,5 +176,8 @@ def paged_decode_attention_kernel(
     )(
         page_table.reshape(-1).astype(jnp.int32),
         seq_lens.astype(jnp.int32),
-        q, pages_k, pages_v,
+        q.reshape(b, 1, h * d),
+        pages_k.reshape(p, ps, kvh * d),
+        pages_v.reshape(p, ps, kvh * d),
     )
+    return out.reshape(b, 1, h, d)

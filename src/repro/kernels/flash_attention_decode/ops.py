@@ -6,9 +6,9 @@ On TPU with aligned shapes: the Pallas kernel.  Off-alignment, or on CPU
 family, so configs that request the kernel path still run everywhere.
 
 Alignment gate (``_aligned``): the kernel streams one (ps, D) page tile per
-grid step, so it wants the page size on a sublane multiple and the head dim
-on a lane multiple; anything else (ragged test pages, odd head dims) takes
-the reference.  ``force_pallas=True`` (tests) bypasses the backend check but
+grid step out of a (P, ps, KVH*D) view of the pool, so it wants the page
+size on a sublane multiple and the head dim on a whole lane tile (128);
+anything else (ragged test pages, 64-wide heads) takes the reference.  ``force_pallas=True`` (tests) bypasses the backend check but
 NOT the alignment gate -- off-alignment parity is exactly what the gate
 exists to avoid having to support in Mosaic.
 """
@@ -24,7 +24,7 @@ from repro.kernels.flash_attention_decode.ref import (
 )
 
 _SUBLANE = 8
-_LANE = 64  # head dims are 64-multiples everywhere in the zoo
+_LANE = 128
 
 
 def _aligned(page_size: int, head_dim: int) -> bool:

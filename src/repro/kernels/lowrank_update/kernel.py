@@ -24,8 +24,11 @@ a pytree into one (B, d, n) tensor and dispatches ONE kernel per bucket.
 B == 1 recovers the single-matrix kernel; the 2-D entry points below are
 thin reshaping wrappers.
 
-Scalar operands (step, lr_alpha, lr_wd) arrive via scalar prefetch so no
-retrace happens when the learning-rate schedule moves.
+Scalar operands (the bias corrections ``1 - b**step``, lr_alpha, lr_wd)
+arrive via scalar prefetch so no retrace happens when the step or the
+learning-rate schedule moves.  The bias corrections are computed by XLA in
+the wrapper, exactly as the jnp references compute them: Mosaic cannot
+lower a power with a traced exponent.
 
 Four inner optimizers are fused (DESIGN.md §2.3/§2.8): ``adam`` (M, V
 moments, bias-corrected), ``msgd`` (single moment, the optimizer of
@@ -56,8 +59,19 @@ from repro.kernels.lowrank_update.quantize import QBLOCK, num_blocks
 # ---------------------------------------------------------------------------
 
 
+def _adam_scalars(step, lr_alpha, lr_wd, b1: float, b2: float) -> jax.Array:
+    """(4,) f32 scalar-prefetch operand [1-b1^t, 1-b2^t, lr_alpha, lr_wd]."""
+    t = step.astype(jnp.float32)
+    return jnp.stack([
+        1.0 - b1**t,
+        1.0 - b2**t,
+        jnp.asarray(lr_alpha, jnp.float32),
+        jnp.asarray(lr_wd, jnp.float32),
+    ])
+
+
 def _adam_kernel(
-    scalars,  # SMEM: (3,) f32 [step, lr_alpha, lr_wd]
+    scalars,  # SMEM: (4,) f32 [bc1, bc2, lr_alpha, lr_wd]
     w_ref,  # (1, bd, bn) in
     p_ref,  # (1, bd, r)
     r_ref,  # (1, r, bn)
@@ -79,15 +93,14 @@ def _adam_kernel(
         r32 = r_ref[0].astype(jnp.float32)
         m_new = b1 * m_ref[0].astype(jnp.float32) + (1.0 - b1) * r32
         v_new = b2 * v_ref[0].astype(jnp.float32) + (1.0 - b2) * r32 * r32
-        t = scalars[0]
-        bc1 = 1.0 - b1**t
-        bc2 = 1.0 - b2**t
+        bc1 = scalars[0]
+        bc2 = scalars[1]
         n_scr[...] = (m_new / bc1) / (jnp.sqrt(v_new / bc2) + eps)
         m_out[0] = m_new.astype(m_out.dtype)
         v_out[0] = v_new.astype(v_out.dtype)
 
-    lr_alpha = scalars[1]
-    lr_wd = scalars[2]
+    lr_alpha = scalars[2]
+    lr_wd = scalars[3]
     delta = jnp.dot(
         p_ref[0].astype(jnp.float32),
         n_scr[...],
@@ -127,11 +140,7 @@ def lowrank_adam_update_batched(
     bn = compat.pick_block(n, block_n)
     grid = (bsz, n // bn, d // bd)
 
-    scalars = jnp.stack([
-        step.astype(jnp.float32),
-        jnp.asarray(lr_alpha, jnp.float32),
-        jnp.asarray(lr_wd, jnp.float32),
-    ])
+    scalars = _adam_scalars(step, lr_alpha, lr_wd, b1, b2)
 
     kernel = functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps)
     w_new, m_new, v_new = pl.pallas_call(
@@ -304,12 +313,12 @@ def lowrank_msgd_update_batched(
 
 
 def _adam_mini_kernel(
-    scalars,  # SMEM: (3,) f32 [step, lr_alpha, lr_wd]
+    scalars,  # SMEM: (4,) f32 [bc1, bc2 (unused), lr_alpha, lr_wd]
     w_ref,  # (1, bd, bn)
     p_ref,  # (1, bd, r)
     r_ref,  # (1, r, bn)
     m_ref,  # (1, r, bn)
-    den_ref,  # (1, r) side='left' | (1, bn) side='right'
+    den_ref,  # (1, r, 1) side='left' | (1, 1, bn) side='right'
     w_out,  # (1, bd, bn)
     m_out,  # (1, r, bn)
     n_scr,  # VMEM scratch (r, bn) f32
@@ -323,15 +332,13 @@ def _adam_mini_kernel(
     def _update_moment():
         r32 = r_ref[0].astype(jnp.float32)
         m_new = b1 * m_ref[0].astype(jnp.float32) + (1.0 - b1) * r32
-        t = scalars[0]
-        bc1 = 1.0 - b1**t
-        den = den_ref[0]
-        den = den[:, None] if side == "left" else den[None, :]
-        n_scr[...] = (m_new / bc1) / den
+        # (r, 1) column ('left') or (1, bn) row ('right'): broadcasts
+        # against the (r, bn) slab either way
+        n_scr[...] = (m_new / scalars[0]) / den_ref[0]
         m_out[0] = m_new.astype(m_out.dtype)
 
-    lr_alpha = scalars[1]
-    lr_wd = scalars[2]
+    lr_alpha = scalars[2]
+    lr_wd = scalars[3]
     delta = jnp.dot(
         p_ref[0].astype(jnp.float32),
         n_scr[...],
@@ -376,18 +383,14 @@ def lowrank_adam_mini_update_batched(
     grid = (bsz, n // bn, d // bd)
 
     v_new, denom = adam_mini_stats_ref(r_g, v, step, b2=b2, eps=eps, side=side)
+    # the denominator keeps its broadcast axis: (B, r, 1) | (B, 1, n), so
+    # each block's last two dims are whole or lane-aligned (Mosaic tiling)
     if side == "left":
-        den_op = denom[..., 0]  # (B, r)
-        den_spec = pl.BlockSpec((1, r), lambda b, i, j, s: (b, 0))
+        den_spec = pl.BlockSpec((1, r, 1), lambda b, i, j, s: (b, 0, 0))
     else:
-        den_op = denom[..., 0, :]  # (B, n)
-        den_spec = pl.BlockSpec((1, bn), lambda b, i, j, s: (b, i))
+        den_spec = pl.BlockSpec((1, 1, bn), lambda b, i, j, s: (b, 0, i))
 
-    scalars = jnp.stack([
-        step.astype(jnp.float32),
-        jnp.asarray(lr_alpha, jnp.float32),
-        jnp.asarray(lr_wd, jnp.float32),
-    ])
+    scalars = _adam_scalars(step, lr_alpha, lr_wd, b1, b2)
 
     kernel = functools.partial(_adam_mini_kernel, b1=b1, side=side)
     w_new, m_new = pl.pallas_call(
@@ -416,7 +419,7 @@ def lowrank_adam_mini_update_batched(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(scalars, w, p, r_g, m, den_op)
+    )(scalars, w, p, r_g, m, denom)
     return w_new, m_new, v_new
 
 
@@ -435,79 +438,50 @@ def lowrank_adam_mini_update_batched(
 # ---------------------------------------------------------------------------
 
 
-def _dq_slab(codes, scale, side: str, signed: bool):
-    """Dequantize a canonical (r, bn) code slab against its scale slab."""
-    r, bn = codes.shape
-    c = codes.astype(jnp.float32)
-    if side == "left":
-        nb = scale.shape[-1]  # (r, nb), nb = bn // QBLOCK
-        c = c.reshape(r, nb, QBLOCK)
-        s = scale[:, :, None]
-        if signed:
-            vals = (c - 127.0) / 127.0 * s
-        else:
-            rel = c / 255.0
-            vals = rel * rel * s
-        return vals.reshape(r, bn)
-    nb_r = scale.shape[-1]  # (bn, nb_r): chunks along the r axis
-    s = jnp.broadcast_to(
-        scale.T[:, None, :], (nb_r, QBLOCK, bn)
-    ).reshape(nb_r * QBLOCK, bn)[:r]
+def _dq(codes, scale, signed: bool):
+    """uint8 codes -> f32 against a broadcastable scale (Mosaic casts
+    uint8 to f32 only through int32)."""
+    c = codes.astype(jnp.int32).astype(jnp.float32)
     if signed:
-        return (c - 127.0) / 127.0 * s
+        return (c - 127.0) / 127.0 * scale
     rel = c / 255.0
-    return rel * rel * s
+    return rel * rel * scale
 
 
-def _q_slab(x, side: str, signed: bool):
-    """Requantize a canonical (r, bn) f32 slab -> (codes, scale slab)."""
-    r, bn = x.shape
-    if side == "left":
-        nb = bn // QBLOCK
-        xb = x.reshape(r, nb, QBLOCK)
-        absmax = jnp.max(jnp.abs(xb), axis=-1)
-        scale = jnp.where(absmax > 0, absmax, 1.0)  # (r, nb)
-        sb = scale[:, :, None]
-        if signed:
-            codes = (
-                jnp.clip(jnp.round(xb / sb * 127.0), -127, 127) + 127
-            ).astype(jnp.uint8)
-        else:
-            rel = jnp.sqrt(jnp.clip(xb / sb, 0.0, 1.0))
-            codes = jnp.clip(jnp.round(rel * 255.0), 0, 255).astype(jnp.uint8)
-        return codes.reshape(r, bn), scale
-    nb_r = num_blocks(r)
-    if nb_r == 1:
-        # one (possibly short) chunk per per-leaf row of length r
-        absmax = jnp.max(jnp.abs(x), axis=0)
-        scale = jnp.where(absmax > 0, absmax, 1.0)  # (bn,)
-        s_full = scale[None, :]
-        scale_out = scale[:, None]  # (bn, 1)
-    else:  # r % QBLOCK == 0 (enforced by the dispatcher)
-        xb = x.reshape(nb_r, QBLOCK, bn)
-        absmax = jnp.max(jnp.abs(xb), axis=1)
-        scale = jnp.where(absmax > 0, absmax, 1.0)  # (nb_r, bn)
-        s_full = jnp.broadcast_to(
-            scale[:, None, :], (nb_r, QBLOCK, bn)
-        ).reshape(r, bn)
-        scale_out = scale.T  # (bn, nb_r)
+def _absmax_scale(x, axis: int):
+    absmax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
+    return jnp.where(absmax > 0, absmax, 1.0)
+
+
+def _q(x, scale, signed: bool):
+    """f32 -> uint8 codes against a broadcastable scale (via int32)."""
     if signed:
-        codes = (
-            jnp.clip(jnp.round(x / s_full * 127.0), -127, 127) + 127
-        ).astype(jnp.uint8)
+        q = jnp.clip(jnp.round(x / scale * 127.0), -127, 127) + 127
     else:
-        rel = jnp.sqrt(jnp.clip(x / s_full, 0.0, 1.0))
-        codes = jnp.clip(jnp.round(rel * 255.0), 0, 255).astype(jnp.uint8)
-    return codes, scale_out
+        rel = jnp.sqrt(jnp.clip(x / scale, 0.0, 1.0))
+        q = jnp.clip(jnp.round(rel * 255.0), 0, 255)
+    return q.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _adam8bit_chunk(r32, mc, ms, vc, vs, *, axis, b1, b2, eps, bc1, bc2):
+    """One quantization chunk: dequant -> Adam moments -> direction ->
+    requant.  ``axis`` is the axis the chunk's scale is shared along."""
+    m_new = b1 * _dq(mc, ms, True) + (1.0 - b1) * r32
+    v_new = b2 * _dq(vc, vs, False) + (1.0 - b2) * r32 * r32
+    n_dir = (m_new / bc1) / (jnp.sqrt(v_new / bc2) + eps)
+    ms_new = _absmax_scale(m_new, axis)
+    vs_new = _absmax_scale(v_new, axis)
+    return (n_dir, _q(m_new, ms_new, True), ms_new,
+            _q(v_new, vs_new, False), vs_new)
 
 
 def _adam8bit_kernel(
-    scalars,  # SMEM: (3,) f32 [step, lr_alpha, lr_wd]
+    scalars,  # SMEM: (4,) f32 [bc1, bc2, lr_alpha, lr_wd]
     w_ref,  # (1, bd, bn)
     p_ref,  # (1, bd, r)
     r_ref,  # (1, r, bn)
     mc_ref,  # (1, r, bn) uint8
-    ms_ref,  # (1, r, nb) 'left' | (1, bn, nb_r) 'right'
+    ms_ref,  # (1, r, nb) 'left' (whole row) | (1, nb_r, bn) 'right'
     vc_ref,  # (1, r, bn) uint8
     vs_ref,
     w_out,
@@ -522,28 +496,60 @@ def _adam8bit_kernel(
     eps: float,
     side: str,
 ):
+    i_n = pl.program_id(1)
     i_d = pl.program_id(2)
+    r, bn = n_scr.shape
 
     @pl.when(i_d == 0)
     def _update_moments():
-        r32 = r_ref[0].astype(jnp.float32)
-        m = _dq_slab(mc_ref[0], ms_ref[0], side, signed=True)
-        v = _dq_slab(vc_ref[0], vs_ref[0], side, signed=False)
-        m_new = b1 * m + (1.0 - b1) * r32
-        v_new = b2 * v + (1.0 - b2) * r32 * r32
-        t = scalars[0]
-        mhat = m_new / (1.0 - b1**t)
-        vhat = v_new / (1.0 - b2**t)
-        n_scr[...] = mhat / (jnp.sqrt(vhat) + eps)
-        mc, ms = _q_slab(m_new, side, signed=True)
-        vc, vs = _q_slab(v_new, side, signed=False)
-        mc_out[0] = mc
-        ms_out[0] = ms
-        vc_out[0] = vc
-        vs_out[0] = vs
+        kw = dict(b1=b1, b2=b2, eps=eps, bc1=scalars[0], bc2=scalars[1])
+        if side == "left":
+            # chunks run along n: this block holds bn // QBLOCK of them.
+            # The (r, nb) scale rows stay resident for the whole batch
+            # slice; a chunk's scale column is picked / written back by a
+            # lane mask (exact: one term survives each sum).
+            nbb = bn // QBLOCK
+            lanes = jax.lax.broadcasted_iota(jnp.int32, ms_ref.shape[1:], 1)
+            ms_all, vs_all = ms_ref[0], vs_ref[0]
+            ms_acc, vs_acc = ms_out[0], vs_out[0]
+            for c in range(nbb):
+                cols = pl.ds(c * QBLOCK, QBLOCK)
+                hit = lanes == i_n * nbb + c
 
-    lr_alpha = scalars[1]
-    lr_wd = scalars[2]
+                def pick(s_all):
+                    return jnp.sum(jnp.where(hit, s_all, 0.0), axis=1,
+                                   keepdims=True)
+
+                n_dir, mc, ms, vc, vs = _adam8bit_chunk(
+                    r_ref[0, :, cols].astype(jnp.float32),
+                    mc_ref[0, :, cols], pick(ms_all),
+                    vc_ref[0, :, cols], pick(vs_all), axis=1, **kw,
+                )
+                n_scr[:, cols] = n_dir
+                mc_out[0, :, cols] = mc
+                vc_out[0, :, cols] = vc
+                ms_acc = jnp.where(hit, ms, ms_acc)
+                vs_acc = jnp.where(hit, vs, vs_acc)
+            ms_out[0] = ms_acc
+            vs_out[0] = vs_acc
+        else:
+            # chunks run along r: one scale row per chunk, per column
+            for k in range(ms_ref.shape[1]):
+                rows = pl.ds(k * QBLOCK, min(QBLOCK, r - k * QBLOCK))
+                n_dir, mc, ms, vc, vs = _adam8bit_chunk(
+                    r_ref[0, rows, :].astype(jnp.float32),
+                    mc_ref[0, rows, :], ms_ref[0, pl.ds(k, 1), :],
+                    vc_ref[0, rows, :], vs_ref[0, pl.ds(k, 1), :],
+                    axis=0, **kw,
+                )
+                n_scr[rows, :] = n_dir
+                mc_out[0, rows, :] = mc
+                vc_out[0, rows, :] = vc
+                ms_out[0, pl.ds(k, 1), :] = ms
+                vs_out[0, pl.ds(k, 1), :] = vs
+
+    lr_alpha = scalars[2]
+    lr_wd = scalars[3]
     delta = jnp.dot(
         p_ref[0].astype(jnp.float32),
         n_scr[...],
@@ -590,9 +596,11 @@ def lowrank_adam8bit_update_batched(
         assert bn % QBLOCK == 0
         nb = n // QBLOCK
         assert m_scale.shape == (bsz, r, nb)
-        scale_spec = pl.BlockSpec(
-            (1, r, bn // QBLOCK), lambda b, i, j, s: (b, 0, i)
-        )
+        # whole (r, nb) scale rows per batch slice: a (1, r, bn // QBLOCK)
+        # block would not be lane-aligned.  The block is revisited across
+        # the n-blocks, so that grid axis runs sequentially.
+        scale_spec = pl.BlockSpec((1, r, nb), lambda b, i, j, s: (b, 0, 0))
+        n_semantics = "arbitrary"
     else:
         nb_r = num_blocks(r)
         assert r <= QBLOCK or r % QBLOCK == 0, (
@@ -600,14 +608,14 @@ def lowrank_adam8bit_update_batched(
         )
         bn = compat.pick_block(n, block_n)
         assert m_scale.shape == (bsz, n, nb_r)
-        scale_spec = pl.BlockSpec((1, bn, nb_r), lambda b, i, j, s: (b, i, 0))
+        # scales enter as (B, nb_r, n): one lane-dense row per chunk
+        m_scale = jnp.swapaxes(m_scale, 1, 2)
+        v_scale = jnp.swapaxes(v_scale, 1, 2)
+        scale_spec = pl.BlockSpec((1, nb_r, bn), lambda b, i, j, s: (b, 0, i))
+        n_semantics = "parallel"
     grid = (bsz, n // bn, d // bd)
 
-    scalars = jnp.stack([
-        step.astype(jnp.float32),
-        jnp.asarray(lr_alpha, jnp.float32),
-        jnp.asarray(lr_wd, jnp.float32),
-    ])
+    scalars = _adam_scalars(step, lr_alpha, lr_wd, b1, b2)
 
     code_spec = pl.BlockSpec((1, r, bn), lambda b, i, j, s: (b, 0, i))
     kernel = functools.partial(
@@ -644,8 +652,11 @@ def lowrank_adam8bit_update_batched(
             jax.ShapeDtypeStruct(v_scale.shape, jnp.float32),
         ],
         compiler_params=compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", n_semantics, "arbitrary"),
         ),
         interpret=interpret,
     )(scalars, w, p, r_g, m_codes, m_scale, v_codes, v_scale)
+    if side == "right":
+        outs = (outs[0], outs[1], jnp.swapaxes(outs[2], 1, 2), outs[3],
+                jnp.swapaxes(outs[4], 1, 2))
     return tuple(outs)

@@ -10,9 +10,13 @@ power_iter):
   proves kernel parity in interpret mode.
 
 ``models/layers.rmsnorm`` routes through here, so every architecture in
-models/ picks up the fused kernel on TPU without touching model code.
+models/ picks up the fused kernel on TPU without touching model code.  The
+kernel path is a ``custom_vjp``: the Pallas forward has no autodiff rule,
+so the backward recomputes through the reference's VJP.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 
@@ -33,7 +37,23 @@ def rmsnorm(
     interpret: bool = False,
 ) -> jax.Array:
     if force_pallas or _on_tpu():
-        return kernel_lib.rmsnorm(
-            x, scale, eps=eps, interpret=interpret or not _on_tpu()
-        )
+        return _rmsnorm_kernel(x, scale, eps, interpret or not _on_tpu())
     return rmsnorm_ref(x, scale, eps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rmsnorm_kernel(x, scale, eps: float, interpret: bool):
+    return kernel_lib.rmsnorm(x, scale, eps=eps, interpret=interpret)
+
+
+def _rmsnorm_fwd(x, scale, eps, interpret):
+    return _rmsnorm_kernel(x, scale, eps, interpret), (x, scale)
+
+
+def _rmsnorm_bwd(eps, interpret, res, g):
+    x, scale = res
+    _, vjp = jax.vjp(lambda x_, s_: rmsnorm_ref(x_, s_, eps), x, scale)
+    return vjp(g)
+
+
+_rmsnorm_kernel.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)

@@ -267,7 +267,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, args) -> dict:
     n_micro = 1
     if shape.kind == "train" and getattr(args, "microbatch", 0):
         n_micro = max(shape.global_batch // args.microbatch, 1)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = _compile_cell(cell, mesh, args)
         t_compile1 = time.time() - t0
         f1, b1, c1, coll1 = _raw_costs(compiled)

@@ -9,13 +9,23 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``.  ``jax.make_mesh``
+    defaults to ``Explicit`` axes, under which bare-``PartitionSpec``
+    sharding constraints and implicitly-resharding gathers are rejected;
+    this code base places arrays through shardings and constraints and
+    lets XLA's SPMD partitioner propagate the rest."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (256 chips/pod) single-pod, or 2x16x16 = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
@@ -24,11 +34,11 @@ def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
         axes = ("data", "model")[: len(shape)] if len(shape) <= 2 else (
             "pod", "data", "model"
         )
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:
@@ -42,43 +52,3 @@ def axes_size(mesh, axes: Tuple[str, ...]) -> int:
     for a in axes:
         n *= mesh.shape[a]
     return n
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names):
-    """``jax.shard_map`` across jax generations.
-
-    New jax: top-level ``jax.shard_map(..., axis_names=..., check_vma=...)``
-    -- partial-auto is first-class, so axes outside ``axis_names`` stay
-    auto/SPMD (TP keeps its sharding inside the region).
-
-    Old jax (<= 0.4.x): ``jax.experimental.shard_map.shard_map``.  The
-    legacy ``auto=...`` partial-auto surface CANNOT lower regions whose
-    auto axes carry real shardings -- XLA's SPMD partitioner dies on a
-    ``CHECK failed: sharding.IsManualSubgroup()`` as soon as an auto-axis
-    (TP) sharded operand appears inside the manual region.  So on old jax
-    every mesh axis goes MANUAL instead: the specs keep naming only the
-    requested ``axis_names``, spec-unmentioned axes mean replicated, so
-    EVERY would-be-auto axis's sharding is gathered at region entry and
-    its dimension computed redundantly per rank -- identical replicated
-    operands produce identical outputs, which is exactly what
-    ``out_specs`` promising replication needs.  That covers TP
-    (``model``) always, and in ``compressed='pod'`` mode also the
-    intra-pod ``data`` axis: each data rank redoes the whole per-pod
-    fwd+bwd (a data-way step-FLOP multiplier on this fallback -- the
-    hierarchical mode keeps only its bandwidth win on old jax).
-    Correctness-first: the memory/compute redundancy is the price of a
-    *working* lowering on the legacy surface; new jax takes the
-    partial-auto fast path above.  Callers that already request every
-    axis manual (e.g. the MoE EP region) are unaffected.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(axis_names), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )

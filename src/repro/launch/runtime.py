@@ -1,4 +1,5 @@
-"""Process-level runtime presets: XLA flags + allocator environment.
+"""Process-level runtime settings: XLA flag presets, allocator environment,
+and the persistent compilation cache location.
 
 This module is deliberately **jax-free**: XLA reads ``XLA_FLAGS`` once, at
 first backend init, so every function here must be callable before ``import
@@ -24,7 +25,11 @@ replaces (SNIPPETS.md snippets 1-3):
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Dict, List, Mapping, MutableMapping, Optional, Sequence, Tuple
+
+# The repository checkout (src/repro/launch/runtime.py -> checkout root).
+_CHECKOUT = Path(__file__).resolve().parents[3]
 
 # ---------------------------------------------------------------------------
 # presets
@@ -32,8 +37,11 @@ from typing import Dict, List, Mapping, MutableMapping, Optional, Sequence, Tupl
 
 # Latency-hiding / async-collective schedule: lets XLA overlap the per-bucket
 # reduce-scatters issued by train/step.py with backward compute instead of
-# serializing them at step end.  Names follow the GPU backend (snippet 1);
-# TPU enables the latency-hiding scheduler by default.
+# serializing them at step end.  Names follow the GPU backend (snippet 1) and
+# are GPU-only: TPU enables the latency-hiding scheduler by default, and
+# libtpu rejects flags it does not know, so no TPU entry point applies this
+# preset (TPU-side flags belong in ``LIBTPU_INIT_ARGS``, which nothing here
+# writes).
 _OVERLAP_FLAGS: Tuple[str, ...] = (
     "--xla_gpu_enable_latency_hiding_scheduler=true",
     "--xla_gpu_enable_async_collectives=true",
@@ -136,3 +144,23 @@ def shell_exports(name: str = "overlap") -> str:
     for key, val in preset.get("env", {}).items():  # type: ignore[union-attr]
         lines.append(f"export {key}={val}")
     return "\n".join(lines)
+
+
+def configure_compile_cache(
+    env: Optional[MutableMapping[str, str]] = None,
+) -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    An existing ``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set.
+    Otherwise the cache goes to ``<checkout>/.jax_cache`` -- a fixed path,
+    because the path is part of what a later process must find again.  JAX
+    reads the variable when it is first imported, so entry points call
+    this before ``import jax``.
+    """
+    if env is None:
+        env = os.environ
+    if env.get("JAX_COMPILATION_CACHE_DIR"):
+        return env["JAX_COMPILATION_CACHE_DIR"]
+    path = str(_CHECKOUT / ".jax_cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = path
+    return path

@@ -31,7 +31,9 @@ def main() -> None:
     ap.add_argument("--max-slots", type=int, default=4)
     ap.add_argument("--page-size", type=int, default=16)
     args = ap.parse_args()
+    from repro.launch.runtime import configure_compile_cache
 
+    configure_compile_cache()  # before jax is imported
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -1,7 +1,7 @@
 """Production training launcher.
 
-Single-host CPU (this container) or multi-host TPU (via
-``jax.distributed.initialize``, auto-detected from TPU env vars / --coordinator).
+Single host (CPU or TPU) or multi-host TPU (via ``jax.distributed.initialize``,
+auto-detected from TPU env vars / --coordinator).
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
         --optimizer galore-sara-adam --steps 100 --smoke
@@ -16,10 +16,10 @@ from __future__ import annotations
 import argparse
 import os
 
-import jax
-
 
 def maybe_init_distributed(args) -> None:
+    import jax
+
     if args.coordinator:
         jax.distributed.initialize(
             coordinator_address=args.coordinator,
@@ -85,8 +85,12 @@ def main() -> None:
     ap.add_argument("--num-processes", type=int, default=1)
     ap.add_argument("--process-id", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.runtime import configure_compile_cache
+
+    configure_compile_cache()  # before jax is imported
     maybe_init_distributed(args)
 
+    import jax
     import jax.numpy as jnp
 
     from repro.configs.base import TrainConfig
@@ -200,7 +204,7 @@ def main() -> None:
         )
 
     if mesh is not None:
-        with mesh:
+        with jax.set_mesh(mesh):
             res = run()
     else:
         res = run()

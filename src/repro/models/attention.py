@@ -222,15 +222,18 @@ def attention(
     chunk_q: int = 512,
     chunk_kv: int = 1024,
 ) -> jax.Array:
-    """Implementation dispatch.  ``auto``: exact for small/decode, chunked
-    for long sequences, pallas on TPU backends."""
+    """Implementation dispatch.  ``auto``: exact for small/decode; for long
+    sequences the Pallas flash kernel on TPU backends (self-attention, where
+    the kernel's contiguous positions hold), chunked jnp otherwise."""
     sq, sk = q.shape[1], k.shape[1]
     if impl == "auto":
         # Exact materializes (B,H,Sq,Sk) logits -- only affordable for small
-        # products and single-query decode; chunked otherwise (the 2048^2
-        # threshold is mirrored in roofline/analysis.py EXACT_ATTN_MAX_ELEMS).
+        # products and single-query decode (the 2048^2 threshold is mirrored
+        # in roofline/analysis.py EXACT_ATTN_MAX_ELEMS).
         if sq == 1 or (sq * sk) <= 2048 * 2048:
             impl = "exact"
+        elif sq == sk and jax.default_backend() == "tpu":
+            impl = "pallas"
         else:
             impl = "chunked"
     if impl == "pallas":

@@ -178,25 +178,16 @@ def chunked_cross_entropy(
 
 
 def manual_axis_names(mesh) -> set:
-    """Mesh axes MANUAL in the current trace context -- i.e. we are inside
-    a ``shard_map`` body over them (e.g. the compressed-DP step, fully
-    manual on old jax).  Manual axes must not be named in sharding
-    constraints: placement over them is already pinned by the enclosing
-    shard_map, and naming one raises at lowering time.
+    """Axes of ``mesh`` -- the abstract mesh of the current trace, from
+    ``jax.sharding.get_abstract_mesh()`` -- whose type is ``Manual``: we
+    are inside a ``shard_map`` over them.  Placement over a manual axis is
+    pinned by the enclosing ``shard_map``, and a sharding constraint may
+    not name it."""
+    from jax.sharding import AxisType
 
-    The trace-context axis env also lists vmap/pmap ``axis_name``
-    bindings, which are not mesh axes and must not suppress constraints:
-    an axis counts as manual only if its name AND bound size match the
-    mesh axis (shard_map always binds the mesh extent).  A vmap axis
-    colliding in both would merely skip the constraint -- a lost layout
-    hint, never wrong numerics -- and no such binding exists in-tree."""
-    try:
-        bound = dict(jax.core.trace_ctx.axis_env.axis_sizes)
-    except Exception:  # axis-env introspection moved; constraints still
-        return set()   # have the call-site try/except as a backstop
     return {
-        name for name, size in bound.items()
-        if name in mesh.axis_names and size == mesh.shape[name]
+        name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+        if kind == AxisType.Manual
     }
 
 
@@ -213,9 +204,8 @@ def shard_activations(x: jax.Array, cfg=None) -> jax.Array:
     a shard_map region only the still-auto axes can be constrained.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.interpreters import pxla
 
-    mesh = pxla.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return x
     manual = manual_axis_names(mesh)
@@ -244,10 +234,4 @@ def shard_activations(x: jax.Array, cfg=None) -> jax.Array:
         spec[1] = "model"
     if all(s is None for s in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except ValueError:
-        # Inside a shard_map manual region (e.g. the compressed-DP step) the
-        # DP axes are Manual and cannot be named in constraints; placement
-        # is already pinned by the enclosing shard_map -- skip.
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

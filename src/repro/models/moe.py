@@ -64,11 +64,12 @@ def init_moe_mlp(key: jax.Array, cfg: ModelConfig) -> PyTree:
 def apply_moe_mlp(
     p: PyTree, x: jax.Array, cfg: ModelConfig
 ) -> Tuple[jax.Array, jax.Array]:
-    """Dispatch: EP ``shard_map`` on a mesh, local ragged_dot otherwise."""
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty or mesh.size == 1 or "model" not in mesh.axis_names:
+    """Dispatch: EP ``shard_map`` on a mesh, local ragged_dot otherwise
+    (also inside a region that is already manual, where each rank holds
+    whole experts)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if (mesh.empty or mesh.size == 1 or "model" not in mesh.axis_names
+            or L.manual_axis_names(mesh)):
         return _apply_moe_local(p, x, cfg)
     return _apply_moe_ep(p, x, cfg, mesh)
 
@@ -156,10 +157,7 @@ def _ep_local_fn(x_loc, router_w, gate_w, up_w, down_w, shared, cfg,
     k = cfg.moe_top_k
     e = cfg.n_experts
     dt = x_loc.dtype
-    if hasattr(jax.lax, "axis_size"):
-        m_size = jax.lax.axis_size("model")
-    else:  # old jax: axis size via a counting psum
-        m_size = jax.lax.psum(1, "model")
+    m_size = jax.lax.axis_size("model")
     m_rank = jax.lax.axis_index("model")
     e_loc = e // m_size
     cap = int(t * k / e * cfg.moe_capacity_factor) + 1
@@ -240,10 +238,9 @@ def _apply_moe_ep(p, x, cfg, mesh):
     import functools
 
     fn = functools.partial(_ep_local_fn, cfg=cfg, dp_axes=dp_axes)
-    # wrap to make `shared` a positional pytree (or None)
-    from repro.launch.mesh import shard_map_compat
-
-    out, aux = shard_map_compat(
+    # wrap to make `shared` a positional pytree (or None); every mesh axis
+    # is manual in the region
+    out, aux = jax.shard_map(
         lambda x_, rw, gw, uw, dw, sh: fn(x_, rw, gw, uw, dw, sh),
         mesh=mesh,
         in_specs=(
@@ -255,7 +252,7 @@ def _apply_moe_ep(p, x, cfg, mesh):
             shared_specs,
         ),
         out_specs=(P(batch_ax, None, None), P()),
-        axis_names=set(mesh.axis_names),
+        check_vma=False,
     )(x, p["router_w"], ew["gate_proj"], ew["up_proj"], ew["down_proj"],
       shared)
     return out, aux

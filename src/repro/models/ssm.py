@@ -164,11 +164,10 @@ def _shard_ssm_heads(x: jax.Array, cfg: ModelConfig, head_axis: int):
     if not cfg.ssm_head_tp:
         return x
     from jax.sharding import PartitionSpec as P
-    from jax.interpreters import pxla
 
     from repro.models.layers import manual_axis_names
 
-    mesh = pxla.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or "model" not in mesh.axis_names:
         return x
     manual = manual_axis_names(mesh)
@@ -186,12 +185,7 @@ def _shard_ssm_heads(x: jax.Array, cfg: ModelConfig, head_axis: int):
         total *= mesh.shape[a]
     if dp and x.shape[0] % total == 0 and x.shape[0] >= total:
         spec[0] = tuple(dp) if len(dp) > 1 else dp[0]
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except ValueError:
-        # inside a manual region whose axes the introspection missed --
-        # placement is already pinned by the enclosing shard_map; skip.
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def apply_ssm_mixer(

@@ -151,6 +151,25 @@ class RooflineReport:
         return max(useful_t, traffic_t) / bound if bound > 0 else 0.0
 
 
+_KERNEL_RE = re.compile(r"jit\((\w+)\)\)*/pallas_call")
+
+
+def pallas_kernel_counts(hlo_text: str) -> Dict[str, int]:
+    """Pallas kernels in a compiled TPU program: its ``tpu_custom_call``
+    instructions grouped by the jitted kernel wrapper that emitted each one
+    (the innermost ``jit(<name>)`` before ``/pallas_call`` in the op
+    metadata, also under a transform: ``jvp(jit(<name>))/pallas_call``).  Counts HLO occurrences: a kernel inside a scanned layer
+    body counts once, not once per layer."""
+    counts: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        names = _KERNEL_RE.findall(line)
+        name = names[-1] if names else "unnamed"
+        counts[name] = counts.get(name, 0) + 1
+    return counts
+
+
 def analyze(
     compiled,
     *,

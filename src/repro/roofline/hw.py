@@ -1,4 +1,6 @@
-"""TPU v5e hardware constants (the TARGET platform; the container is CPU)."""
+"""TPU v5e per-chip constants for the analytic roofline model (published
+figures, Google Cloud "TPU v5e" documentation).  These feed models and
+dry-run estimates only; a measured run reads ``device_kind`` from JAX."""
 from __future__ import annotations
 
 PEAK_FLOPS_BF16 = 197e12  # per chip, bf16

@@ -7,9 +7,10 @@ Two training-step flavors:
   TP over ``model``, DP over ``pod``+``data``).
 
 * ``compressed`` -- the beyond-paper *project-then-reduce* schedule: the step
-  is a ``shard_map`` manual over the DP axes (``model`` stays auto/SPMD on
-  new jax; old jax lowers the region fully manual -- see
-  ``launch/mesh.shard_map_compat``).  Per-shard gradients of low-rank
+  is a ``shard_map`` over the whole mesh whose specs split the batch over
+  the DP axes only, so every other axis is replicated inside the region
+  (see ``compressed_step_fn`` for why it is not partial-auto).  Per-shard
+  gradients of low-rank
   leaves are projected to R-space BEFORE the cross-replica mean, shrinking
   DP gradient traffic by ~d/r on every non-refresh step (exact by
   linearity; P is replicated).  With a bucket-native optimizer the
@@ -45,7 +46,7 @@ from repro.configs.base import TrainConfig
 from repro.core import buckets as buckets_lib
 from repro.core import lowrank as lowrank_lib
 from repro.launch import sharding as shd
-from repro.launch.mesh import axes_size, batch_axes, shard_map_compat
+from repro.launch.mesh import axes_size, batch_axes
 from repro.models.model_zoo import Model
 from repro.train.state import TrainState
 
@@ -298,17 +299,17 @@ def make_train_step(
     def compressed_step_fn(
         state: TrainState, batch, *, refresh: bool, group: int = 0
     ):
-        # 'pod' compression mode: only the slow INTER-POD axis goes manual --
-        # gradients are projected to R-space before crossing pods, while
-        # FSDP/TP over (data, model) stay fully auto inside each pod.  This
-        # is the hierarchical schedule the flat-compressed experiments showed
-        # is needed at scale (EXPERIMENTS.md §Perf cell 3).
+        # 'pod' compression mode: only the slow INTER-POD axis carries the
+        # compressed reduction -- gradients are projected to R-space before
+        # crossing pods.  This is the hierarchical schedule the flat-
+        # compressed experiments showed is needed at scale (EXPERIMENTS.md
+        # §Perf cell 3).  Inside the (fully manual) region each pod's
+        # (data, model) ranks compute the whole per-pod step redundantly.
         # the pod axis is validated at build time in make_train_step
         dp = ("pod",) if compressed == "pod" else batch_axes(mesh)
         if compressed == "pod":
-            # manual only over 'pod': dim0 splits across pods; the intra-pod
-            # data sharding of the per-pod view stays auto.  0-dim entries
-            # (the fault-injection grad_scale scalar) replicate.
+            # dim0 splits across pods only.  0-dim entries (the
+            # fault-injection grad_scale scalar) replicate.
             batch_specs = jax.tree_util.tree_map(
                 lambda x: P("pod", *([None] * (x.ndim - 1)))
                 if x.ndim and x.shape[0] % mesh.shape["pod"] == 0 else P(),
@@ -420,12 +421,19 @@ def make_train_step(
         # exit; everything else (params, rest-of-state, metrics) is
         # replicated exactly as before.
         state_specs = shd.zero_state_specs(state, dp) if zero else P()
-        return shard_map_compat(
+        # Every mesh axis is manual; the specs name only the DP axes, so
+        # the non-DP axes (``model``, and ``data`` in 'pod' mode) are
+        # gathered at region entry and computed redundantly per rank.  A
+        # partial-auto region (only ``dp`` manual) would keep TP inside,
+        # but XLA's SPMD partitioner aborts on the embedding gather of an
+        # auto-sharded table there (``PartitionGatherTrivialSliced...``),
+        # and Pallas custom calls cannot be auto-partitioned either.
+        return jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, P()),
-            axis_names=set(dp),
+            check_vma=False,
         )(state, batch)
 
     base = compressed_step_fn if compressed else step_fn

@@ -53,7 +53,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     fns0 = make_train_step(model, opt, donate=False)
     s0, m0 = fns0["jit_step"](state, batch)
     mesh = make_mesh((4, 2))
-    with mesh:
+    with jax.set_mesh(mesh):
         st, _ = shard_train_state(state, mesh)
         bsh = jax.device_put(batch, shd.batch_shardings(batch, mesh))
         fns = make_train_step(model, opt, mesh=mesh, donate=False)
@@ -68,10 +68,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
 
 def test_compressed_dp_equals_standard():
-    # On old jax (no top-level jax.shard_map) this exercises
-    # shard_map_compat's FULLY-MANUAL fallback lowering -- the legacy
-    # partial-auto surface dies in XLA's IsManualSubgroup check; see
-    # launch/mesh.py.  On new jax it takes the partial-auto fast path.
+    # The compressed step is one shard_map, manual over every mesh axis
+    # (train/step.py compressed_step_fn).
     out = run_sub("""
     cfg = get_config("llama3-8b", smoke=True).with_(dtype=jnp.float32,
                                                     n_layers=2)
@@ -81,7 +79,7 @@ def test_compressed_dp_equals_standard():
     state = TrainState(params, opt.init(params))
     batch = concrete_train_batch(cfg, 8, 32)
     mesh = make_mesh((4, 2))
-    with mesh:
+    with jax.set_mesh(mesh):
         st, _ = shard_train_state(state, mesh)
         bsh = jax.device_put(batch, shd.batch_shardings(batch, mesh))
         s1, _ = make_train_step(model, opt, mesh=mesh,
@@ -112,7 +110,7 @@ def test_compression_reduces_dp_allreduce_bytes():
     batch = concrete_train_batch(cfg, 8, 32)
     mesh = make_mesh((8, 1))  # pure DP so all collectives are grad syncs
     sizes = {}
-    with mesh:
+    with jax.set_mesh(mesh):
         ssh = shd.tree_shardings(state, mesh)
         bsh = shd.batch_shardings(batch, mesh)
         for name, comp in (("std", False), ("cmp", True)):
@@ -139,7 +137,7 @@ def test_moe_ep_equals_local_on_mesh():
                           (4, 16, cfg.d_model)) * 0.5
     out_local, _ = moe_lib._apply_moe_local(p, x, cfg)
     mesh = make_mesh((2, 4))
-    with mesh:
+    with jax.set_mesh(mesh):
         out_ep, _ = jax.jit(lambda p_, x_: moe_lib.apply_moe_mlp(
             p_, x_, cfg))(p, x)
     err = float(jnp.max(jnp.abs(out_local - out_ep)))
@@ -183,7 +181,7 @@ print("SAVED")
     opt = make_optimizer("galore-sara-adam", params, rank=8)
     skeleton = TrainState(params, opt.init(params))
     mesh = make_mesh((4, 2))
-    with mesh:
+    with jax.set_mesh(mesh):
         sh = shd.tree_shardings(skeleton, mesh)
         restored = CheckpointManager({ckpt!r}, keep=1).load(
             skeleton, shardings=sh)
@@ -251,7 +249,7 @@ def test_compressed_parity_matrix_bucketed():
         opt_r = make_optimizer("galore-sara-adam", params,
                                engine="reference", **kw)
         assert opt_b.state_layout is not None  # stacked psum payload
-        with mesh:
+        with jax.set_mesh(mesh):
             st_b, _ = shard_train_state(
                 TrainState(params, opt_b.init(params)), mesh)
             st_r, _ = shard_train_state(
@@ -302,7 +300,7 @@ def test_compressed_resume_crosses_engines(tmp_path):
                            **kw)
     opt_r = make_optimizer("galore-sara-adam", params, engine="reference",
                            **kw)
-    with mesh:
+    with jax.set_mesh(mesh):
         bsh = jax.device_put(batch, shd.batch_shardings(batch, mesh))
         # compressed bucketed run: refresh + hot step, then checkpoint
         st, _ = shard_train_state(TrainState(params, opt_b.init(params)),
@@ -371,7 +369,7 @@ def test_zero_sharded_compressed_matches_replicated():
     opt_r = make_optimizer("galore-sara-adam", params, **kw)
     opt_z = make_optimizer("galore-sara-adam", params,
                            state_sharding="zero", state_shards=4, **kw)
-    with mesh:
+    with jax.set_mesh(mesh):
         bsh = jax.device_put(batch, shd.batch_shardings(batch, mesh))
         st_r, _ = shard_train_state(TrainState(params, opt_r.init(params)),
                                     mesh)
@@ -420,7 +418,7 @@ def test_zero_sharded_compressed_matches_replicated():
     mesh_p = make_mesh((2, 2, 2), ("pod", "data", "model"))
     opt_zp = make_optimizer("galore-sara-adam", params,
                             state_sharding="zero", state_shards=2, **kw)
-    with mesh_p:
+    with jax.set_mesh(mesh_p):
         bsh = jax.device_put(batch, shd.batch_shardings(batch, mesh_p))
         st_r, _ = shard_train_state(TrainState(params, opt_r.init(params)),
                                     mesh_p)
@@ -491,7 +489,7 @@ def test_compressed_step_psums_one_operand_per_bucket():
             perleaf_rspace.add(tuple(p.shape))  # old refresh payload
 
     plan = opt.bucket_plan
-    with mesh:
+    with jax.set_mesh(mesh):
         fns = make_train_step(model, opt, mesh=mesh, compressed="flat",
                               donate=False)
         for refresh in (False, True):

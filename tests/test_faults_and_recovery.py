@@ -403,7 +403,7 @@ def test_zero_sharded_skip_gate_lockstep():
     from repro.core import make_optimizer
     from repro.core import buckets as buckets_lib
     from repro.core.lowrank import StackedGrads, project_grads_stacked
-    from repro.launch.mesh import make_mesh, shard_map_compat
+    from repro.launch.mesh import make_mesh
     from repro.launch import sharding as shd
     from repro.train.state import TrainState
 
@@ -446,10 +446,9 @@ def test_zero_sharded_skip_gate_lockstep():
             shard_axes=("data",))
         return TrainState(p2, st2), aux.skipped * jnp.ones((1,), jnp.float32)
 
-    with mesh:
-        run = shard_map_compat(body, mesh=mesh, in_specs=(sspec, gspec),
-                               out_specs=(sspec, P("data")),
-                               axis_names={"data"})
+    with jax.set_mesh(mesh):
+        run = jax.shard_map(body, mesh=mesh, in_specs=(sspec, gspec),
+                            out_specs=(sspec, P("data")), check_vma=False)
         out_bad, skipped_bad = run(state, sg_bad)
         out_ok, skipped_ok = run(state, sg_ok)
 

@@ -537,7 +537,7 @@ def test_fault_matrix_and_elastic_resume_on_8_devices():
 
     base = tempfile.mkdtemp()
     ckdir = os.path.join(base, "ck")
-    with mesh:
+    with jax.set_mesh(mesh):
         st, sh = shard_train_state(TrainState(params, opt.init(params)),
                                    mesh, zero_dp_axes=("data",))
         fns = make_train_step(model, opt, mesh=mesh, compressed="flat",
@@ -650,7 +650,7 @@ def test_fault_matrix_and_elastic_resume_on_8_devices():
     # and the resumed state trains at the new world size: one compressed
     # step on a (2, 4) mesh (DP extent 2 == new shard count)
     mesh2 = make_mesh((2, 4))
-    with mesh2:
+    with jax.set_mesh(mesh2):
         st2, sh2 = shard_train_state(got2, mesh2, zero_dp_axes=("data",))
         fns2 = make_train_step(model, opt2, mesh=mesh2, compressed="flat",
                                donate=False)

@@ -58,7 +58,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.roofline.analysis import collective_stats
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2))
 xs = NamedSharding(mesh, P("data", None))
 ws = NamedSharding(mesh, P(None, "model"))
 def f(a, w):
@@ -128,3 +129,22 @@ def test_hw_constants():
     assert hw.PEAK_FLOPS_BF16 == 197e12
     assert hw.HBM_BW == 819e9
     assert hw.ICI_LINK_BW == 50e9
+
+
+def test_pallas_kernel_counts_groups_by_wrapper():
+    from repro.roofline.analysis import pallas_kernel_counts
+
+    def line(op_name):
+        return (f'  %k = f32[8,128] custom-call(%a), custom_call_target='
+                f'"tpu_custom_call", metadata={{op_name="{op_name}"}}')
+
+    hlo = "\n".join([
+        line("jit(step)/jit(lowrank_adam_update_batched)/pallas_call"),
+        line("jit(step)/while/body/jit(lowrank_adam_update_batched)"
+             "/pallas_call"),
+        line("jit(loss)/jvp(jit(flash_attention_fwd))/pallas_call"),
+        '  %c = f32[8] custom-call(%a), custom_call_target="Sharding"',
+    ])
+    assert pallas_kernel_counts(hlo) == {
+        "lowrank_adam_update_batched": 2, "flash_attention_fwd": 1,
+    }

@@ -110,3 +110,21 @@ def test_importing_dryrun_does_not_mutate_environ():
     )
     assert out.returncode == 0, out.stderr
     assert "OK" in out.stdout
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path():
+    from repro.launch.runtime import configure_compile_cache
+
+    env = {}
+    path = configure_compile_cache(env)
+    assert path == os.path.join(REPO, ".jax_cache")
+    assert env == {"JAX_COMPILATION_CACHE_DIR": path}
+    assert configure_compile_cache({}) == path  # same path every call
+
+
+def test_compile_cache_respects_existing_setting():
+    from repro.launch.runtime import configure_compile_cache
+
+    env = {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}
+    assert configure_compile_cache(env) == "/elsewhere/cache"
+    assert env == {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}

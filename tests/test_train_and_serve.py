@@ -327,7 +327,7 @@ def test_microbatch_accum_dtype_matches_unaccumulated():
 
 
 def test_compressed_kwarg_normalization(setup):
-    from repro.launch.mesh import single_device_mesh
+    from repro.launch.mesh import make_mesh, single_device_mesh
 
     cfg, model, opt, data = setup
     mesh = single_device_mesh()
@@ -335,7 +335,7 @@ def test_compressed_kwarg_normalization(setup):
     fns = make_train_step(model, opt, mesh=mesh, compressed=True,
                           donate=False)
     assert fns["compressed_mode"] == "flat"
-    pod_mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    pod_mesh = make_mesh((1, 1, 1))
     fns = make_train_step(model, opt, mesh=pod_mesh, compressed="pod",
                           donate=False)
     assert fns["compressed_mode"] == "pod"
