@@ -496,6 +496,8 @@ def make_lowrank_optimizer(
                 inner_state = inner_state._replace(m=m2.astype(m.dtype))
         return LeafState(projector=new_p, inner=inner_state), overlap
 
+    # the hot path's ops carry the scope; the refresh's nest "opt_refresh"
+    @jax.named_scope("opt_update")
     def update(
         grads: PyTree,
         state: LowRankOptState,
@@ -724,16 +726,17 @@ def make_lowrank_optimizer(
                             gs, keys, old_ps, pcfg, rank=rank
                         )
 
-                new_bucket_states, bucket_overlaps = (
-                    buckets_lib.bucketed_refresh(
-                        state_layout, state.buckets, flat_specs,
-                        flat_grads, subkey, _refresh_fn,
-                        group=group % max(cfg.refresh_groups, 1),
-                        momentum_carry=cfg.momentum_carry,
-                        stacked_refresh_fn=_stacked_fn,
-                        stacked_grads=stacked_grads,
+                with jax.named_scope("opt_refresh"):
+                    new_bucket_states, bucket_overlaps = (
+                        buckets_lib.bucketed_refresh(
+                            state_layout, state.buckets, flat_specs,
+                            flat_grads, subkey, _refresh_fn,
+                            group=group % max(cfg.refresh_groups, 1),
+                            momentum_carry=cfg.momentum_carry,
+                            stacked_refresh_fn=_stacked_fn,
+                            stacked_grads=stacked_grads,
+                        )
                     )
-                )
                 overlaps.extend(bucket_overlaps)
             if shard_local and not refresh:
                 # ZeRO hot step: slice this shard's W rows, run the fused
@@ -801,7 +804,8 @@ def make_lowrank_optimizer(
 
             if refresh and spec.group == (group % max(cfg.refresh_groups, 1)):
                 lkey = jax.random.fold_in(subkey, i)
-                st, ov = _refresh_leaf(spec, st, g, lkey)
+                with jax.named_scope("opt_refresh"):
+                    st, ov = _refresh_leaf(spec, st, g, lkey)
                 overlaps.append(ov)
 
             proj = st.projector

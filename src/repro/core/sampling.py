@@ -75,8 +75,9 @@ def sara_select(
     that pool (documented deviation; ``exact`` backend gives k = d choices
     as in the paper).
     """
-    idx = gumbel_topk_indices(s, r, key, sort_indices=True)
-    p = jnp.take(u, idx, axis=-1)
+    with jax.named_scope("sara_sample"):
+        idx = gumbel_topk_indices(s, r, key, sort_indices=True)
+        p = jnp.take(u, idx, axis=-1)
     return p, idx
 
 

@@ -119,19 +119,24 @@ def randomized_svd_stacked(
     g = g.astype(jnp.float32)
     _, m, n = g.shape
     k, kp, power_iters = clamp_sketch(m, n, k, oversample, power_iters)
-    omega = jax.vmap(
-        lambda kk: jax.random.normal(kk, (n, kp), dtype=jnp.float32)
-    )(keys)
-    y = jnp.einsum("bmn,bnk->bmk", g, omega)  # (B, m, kp) sketch
+    with jax.named_scope("sketch"):
+        omega = jax.vmap(
+            lambda kk: jax.random.normal(kk, (n, kp), dtype=jnp.float32)
+        )(keys)
+        y = jnp.einsum("bmn,bnk->bmk", g, omega)  # (B, m, kp) sketch
     for _ in range(power_iters):
         # Thin QR keeps the iteration bounded; the GEMM pair is fused.
-        q, _ = jnp.linalg.qr(y)
-        y = power_ops.power_iter_step(g, q)
-    q, _ = jnp.linalg.qr(y)  # (B, m, kp) orthonormal range basis
-    b = jnp.einsum("bmk,bmn->bkn", q, g)  # (B, kp, n) small
-    ub, s, _ = jnp.linalg.svd(b, full_matrices=False)
-    u = jnp.einsum("bmk,bkj->bmj", q, ub)  # (B, m, kp)
-    return u[..., :k], s[..., :k]
+        with jax.named_scope("qr"):
+            q, _ = jnp.linalg.qr(y)
+        with jax.named_scope("power_iter"):
+            y = power_ops.power_iter_step(g, q)
+    with jax.named_scope("qr"):
+        q, _ = jnp.linalg.qr(y)  # (B, m, kp) orthonormal range basis
+    with jax.named_scope("small_svd"):
+        b = jnp.einsum("bmk,bmn->bkn", q, g)  # (B, kp, n) small
+        ub, s, _ = jnp.linalg.svd(b, full_matrices=False)
+        u = jnp.einsum("bmk,bkj->bmj", q, ub)  # (B, m, kp)
+        return u[..., :k], s[..., :k]
 
 
 def _as_key_stack(key: jax.Array) -> jax.Array:

@@ -231,16 +231,21 @@ def _scan_blocks(block_fn, blocks: PyTree, x: jax.Array, cfg: ModelConfig,
 
     if cfg.remat == "block":
         body = jax.checkpoint(body)
-    (x, aux_sum), kvs = scan_or_loop(
-        body, (x, jnp.zeros((), jnp.float32)), blocks, scan=cfg.scan_layers,
-        unroll=cfg.scan_unroll,
-    )
+    # around the scan, so its slicing of the stacked weights and stacking
+    # of their gradients carry the scope too; the recomputation under
+    # remat keeps it as well (".../checkpoint/rematted_computation/...")
+    with jax.named_scope("blocks"):
+        (x, aux_sum), kvs = scan_or_loop(
+            body, (x, jnp.zeros((), jnp.float32)), blocks,
+            scan=cfg.scan_layers, unroll=cfg.scan_unroll,
+        )
     return x, kvs, aux_sum
 
 
 def embed_tokens(params: PyTree, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
-    h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-    return L.shard_activations(h, cfg)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+        return L.shard_activations(h, cfg)
 
 
 def forward_hidden(
@@ -280,9 +285,10 @@ def loss_fn(
         # Prefix positions carry no next-token loss.
         pad = jnp.full(prefix.shape[:2], -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
-    loss, n_tok = L.chunked_cross_entropy(
-        h, lm_head_matrix(params, cfg), labels, cfg.loss_chunk
-    )
+    with jax.named_scope("head_loss"):
+        loss, n_tok = L.chunked_cross_entropy(
+            h, lm_head_matrix(params, cfg), labels, cfg.loss_chunk
+        )
     total = loss + aux_weight * aux / max(cfg.n_layers, 1)
     return total, {"loss": loss, "aux": aux, "tokens": n_tok}
 

@@ -27,6 +27,14 @@ continue; ``max_rollbacks`` bounds the budget before the classic
 manager and, under recovery, counted instead of fatal.  Fault injection for
 all of this lives in ``train/faults.py`` (a ``FaultPlan`` passes hooks and
 a checkpoint-I/O shim through the same seams).
+
+Profiling: each iteration is a ``jax.profiler.StepTraceAnnotation("train")``
+holding host spans on the profiler's clock -- ``repro.loop.data`` (the
+batch and its hooks), ``.dispatch`` (the step call), ``.fetch`` (the metric
+fetch, the loop's device sync), ``.checkpoint``, ``.rebucket`` and
+``.spectrum``.  The step time in ``history`` is taken at each fetch, over
+the steps it completed; ``compiles`` / ``compile_s`` count the process's
+backend compiles since the loop began.
 """
 from __future__ import annotations
 
@@ -49,6 +57,9 @@ from repro.train import state as state_lib
 from repro.train.state import TrainState
 
 PyTree = Any
+# host spans on the profiler's clock (``repro.loop.*``); with the profiler
+# off each costs about a microsecond
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -299,7 +310,10 @@ def train_loop(
     def _safe_save(cur_state, s: int, blocking: bool) -> None:
         _drain_save_error()  # an old failure must not eat THIS save
         try:
-            manager.save(cur_state, s, blocking=blocking, meta=_ckpt_meta())
+            with _span("repro.loop.checkpoint"):
+                manager.save(
+                    cur_state, s, blocking=blocking, meta=_ckpt_meta()
+                )
         except Exception as e:
             monitor.save_failures += 1
             if recovery is None:
@@ -327,15 +341,25 @@ def train_loop(
     # and ``history`` come out identical to the per-step fetch -- only the
     # moment the NaN sentinel (or the divergence detector) can raise moves
     # to the fetch point.
-    pending: List = []  # (step, device metrics dict, health floats)
+    pending: List = []  # (step, device metrics dict, refreshed)
 
     def _flush_metrics(cur_state, swallow_aborts=False):
+        if not pending:
+            return
+        with _span("repro.loop.fetch"):
+            # the newest step's metrics are ready once the device has run
+            # every pending step: this sync ends their wall time
+            jax.block_until_ready(pending[-1][1])
+            health = monitor.end_step(pending[-1][0], steps=len(pending))
+            _drain_pending(cur_state, health, swallow_aborts)
+
+    def _drain_pending(cur_state, health, swallow_aborts):
         # drains entry-by-entry so an abort (or rollback trigger) mid-flush
         # never re-processes (or drops) already-fetched losses; the
         # finally-path flush swallows instead of masking an in-flight
         # exception
         while pending:
-            s, m, health = pending.pop(0)
+            s, m, refreshed = pending.pop(0)
             loss = float(m["loss"])
             skipped = (
                 float(np.asarray(m["skipped"])) if "skipped" in m else 0.0
@@ -377,6 +401,9 @@ def train_loop(
                     **{k: float(v) for k, v in health.items()},
                     **monitor.counters(),
                 }
+                if refreshed and "refresh_overlap" in m:
+                    # ||P_new^T P_old||_F^2 / r: 1 is a frozen subspace
+                    rec["refresh_overlap"] = float(m["refresh_overlap"])
                 if heartbeats is not None:
                     rec["stale_workers"] = float(len(heartbeats.stale()))
                 if eval_fn is not None:
@@ -443,6 +470,7 @@ def train_loop(
 
     step = start_step
     final_step = train_cfg.total_steps
+    monitor.start_step()
     # the step of the most recent checkpoint KNOWN loadable (restored from
     # or pinned at start) -- reported on rollback exhaustion so the abort
     # message names where a manual restart can resume
@@ -452,150 +480,156 @@ def train_loop(
     )
     try:
         while step < train_cfg.total_steps:
-            try:
-                if fault_plan is not None:
-                    fault_plan.maybe_kill(step)  # injected process loss
-                batch = data.batch_at(step)
-                if batch_hook is not None:
-                    batch = batch_hook(batch)
-                if fault_plan is not None:
-                    batch = fault_plan.batch_hook(batch, step)
-                if heartbeats is not None:
-                    heartbeats.beat(worker_name)
-                    # staleness is evaluated EVERY step (not just at
-                    # log_every cadence): each newly-stale worker is
-                    # recorded with its first-stale step and escalated
-                    # per the policy's stale_worker_action.
-                    for w in heartbeats.check(step):
-                        history.append({
-                            "event": "stale_worker",
-                            "worker": w,
-                            "step": float(step),
-                            "first_stale_step": float(
-                                heartbeats.first_stale[w]
-                            ),
-                            "action": stale_action,
-                        })
-                        if stale_action == "abort":
-                            raise RuntimeError(
-                                f"worker {w!r} heartbeat stale at step "
-                                f"{step}; aborting per policy"
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                try:
+                    if fault_plan is not None:
+                        fault_plan.maybe_kill(step)  # injected process loss
+                    with _span("repro.loop.data"):
+                        batch = data.batch_at(step)
+                        if batch_hook is not None:
+                            batch = batch_hook(batch)
+                        if fault_plan is not None:
+                            batch = fault_plan.batch_hook(batch, step)
+                    if heartbeats is not None:
+                        heartbeats.beat(worker_name)
+                        # staleness is evaluated EVERY step (not just at
+                        # log_every cadence): each newly-stale worker is
+                        # recorded with its first-stale step and escalated
+                        # per the policy's stale_worker_action.
+                        for w in heartbeats.check(step):
+                            history.append({
+                                "event": "stale_worker",
+                                "worker": w,
+                                "step": float(step),
+                                "first_stale_step": float(
+                                    heartbeats.first_stale[w]
+                                ),
+                                "action": stale_action,
+                            })
+                            if stale_action == "abort":
+                                raise RuntimeError(
+                                    f"worker {w!r} heartbeat stale at step "
+                                    f"{step}; aborting per policy"
+                                )
+                            if stale_action == "rollback":
+                                raise recovery_lib.RollbackNeeded(
+                                    step, f"stale worker {w!r}"
+                                )
+                    if fault_plan is not None:
+                        dt = fault_plan.sleep_s(step)
+                        if dt > 0:
+                            time.sleep(dt)  # straggler injection
+                    # Staggered refresh: group g refreshes at steps where
+                    # step % (tau/groups) == 0, cycling groups (DESIGN.md §2).
+                    sub_tau = max(tau // groups, 1)
+                    is_refresh = step % sub_tau == 0
+                    if is_refresh:
+                        group = (step // sub_tau) % groups
+                        if spectrum is not None:
+                            # host-snapshot the probe leaf BEFORE dispatch:
+                            # the jitted step donates its input state
+                            with _span("repro.loop.spectrum"):
+                                spectrum.capture_before(state.params, group)
+                        with _span("repro.loop.dispatch"):
+                            state, m = step_fns["jit_refresh_step"](
+                                state, batch, group=group
                             )
-                        if stale_action == "rollback":
-                            raise recovery_lib.RollbackNeeded(
-                                step, f"stale worker {w!r}"
-                            )
-                monitor.start_step()
-                if fault_plan is not None:
-                    dt = fault_plan.sleep_s(step)
-                    if dt > 0:
-                        time.sleep(dt)  # straggler injection
-                # Staggered refresh: group g refreshes at steps where
-                # step % (tau/groups) == 0, cycling groups (DESIGN.md §2).
-                sub_tau = max(tau // groups, 1)
-                is_refresh = step % sub_tau == 0
-                if is_refresh:
-                    group = (step // sub_tau) % groups
-                    if spectrum is not None:
-                        # host-snapshot the probe leaf BEFORE dispatch:
-                        # the jitted step donates its input state
-                        spectrum.capture_before(state.params, group)
-                    state, m = step_fns["jit_refresh_step"](
-                        state, batch, group=group
+                    else:
+                        with _span("repro.loop.dispatch"):
+                            state, m = step_fns["jit_step"](state, batch)
+                    if fault_plan is not None:
+                        m = fault_plan.loss_hook(step, m)
+                    pending.append((step, m, is_refresh))
+                    if spectrum is not None and is_refresh:
+                        with _span("repro.loop.spectrum"):
+                            rec = spectrum.observe(state.params, step, group)
+                        if rec is not None and getattr(
+                            train_cfg, "log_spectrum", False
+                        ):
+                            history.append(rec)
+                    if tracker is not None and is_refresh:
+                        projs = metrics_lib.collect_projectors(
+                            state.opt_state, optimizer.specs,
+                            layout=optimizer.state_layout,
+                        )
+                        tracker.observe(
+                            {k: np.asarray(v) for k, v in projs.items()}
+                        )
+                    if fault_plan is not None and fault_plan.preempt(step):
+                        guard.requested = True  # as if SIGTERM were delivered
+                    checkpoint_due = (
+                        train_cfg.checkpoint_every > 0
+                        and (step + 1) % train_cfg.checkpoint_every == 0
                     )
-                else:
-                    state, m = step_fns["jit_step"](state, batch)
-                if fault_plan is not None:
-                    m = fault_plan.loss_hook(step, m)
-                health = monitor.end_step(step)
-                pending.append((step, m, health))
-                if spectrum is not None and is_refresh:
-                    rec = spectrum.observe(state.params, step, group)
-                    if rec is not None and getattr(
-                        train_cfg, "log_spectrum", False
+                    if (
+                        is_refresh
+                        or checkpoint_due
+                        or guard.requested
+                        or step % log_every == 0
+                        or step == train_cfg.total_steps - 1
                     ):
-                        history.append(rec)
-                if tracker is not None and is_refresh:
-                    projs = metrics_lib.collect_projectors(
-                        state.opt_state, optimizer.specs,
-                        layout=optimizer.state_layout,
-                    )
-                    tracker.observe(
-                        {k: np.asarray(v) for k, v in projs.items()}
-                    )
-                if fault_plan is not None and fault_plan.preempt(step):
-                    guard.requested = True  # as if SIGTERM were delivered
-                checkpoint_due = (
-                    train_cfg.checkpoint_every > 0
-                    and (step + 1) % train_cfg.checkpoint_every == 0
-                )
-                if (
-                    is_refresh
-                    or checkpoint_due
-                    or guard.requested
-                    or step % log_every == 0
-                    or step == train_cfg.total_steps - 1
-                ):
-                    _flush_metrics(state)
-                if rank_sched is not None and is_refresh:
-                    state = _maybe_rebucket(state, step, group)
-                if checkpoint_due:
-                    _safe_save(
-                        state, step + 1,
-                        blocking=not train_cfg.async_checkpoint,
-                    )
-                if guard.requested:
-                    _safe_save(state, step + 1, blocking=True)
-                    final_step = step + 1
-                    break
-                step += 1
-            except recovery_lib.RollbackNeeded as rb:
-                attempt = monitor.rollbacks + 1
-                if attempt > recovery.max_rollbacks:
-                    raise FloatingPointError(
-                        f"divergence persists after "
-                        f"{recovery.max_rollbacks} rollbacks ({rb}); "
-                        f"last verified step {last_verified}"
-                    ) from rb
-                monitor.rollbacks = attempt
-                backoff = recovery.backoff_s(attempt)
-                if backoff > 0:
-                    time.sleep(backoff)
-                _drain_save_error()  # never race an in-flight save
-                state, ck_step = _restore_latest(state)
-                last_verified = ck_step
-                if recovery.resample_on_rollback:
-                    # fold the attempt into the refresh RNG: stochastic
-                    # selection (sara/golore/grass) draws a DIFFERENT
-                    # subspace at the next refresh instead of replaying
-                    # the diverged one (dominant re-selects the same
-                    # subspace by construction -- see train/recovery.py)
-                    state = TrainState(
-                        state.params,
-                        recovery_lib.resample_opt_state(
-                            state.opt_state, attempt
-                        ),
-                    )
-                # truncate host-side records to the rollback point
-                if ck_step <= loss_base:
-                    losses.clear()
-                    loss_base = ck_step
-                else:
-                    del losses[ck_step - loss_base:]
-                history[:] = [
-                    r for r in history if r.get("step", -1.0) < ck_step
-                ]
-                pending.clear()
-                detector.reset()
-                monitor.bad_loss_count = 0
-                history.append({
-                    "event": "rollback",
-                    "step": float(ck_step),
-                    "from_step": float(rb.step),
-                    "attempt": float(attempt),
-                    "reason": rb.reason,
-                })
-                step = ck_step
+                        _flush_metrics(state)
+                    if rank_sched is not None and is_refresh:
+                        with _span("repro.loop.rebucket"):
+                            state = _maybe_rebucket(state, step, group)
+                    if checkpoint_due:
+                        _safe_save(
+                            state, step + 1,
+                            blocking=not train_cfg.async_checkpoint,
+                        )
+                    if guard.requested:
+                        _safe_save(state, step + 1, blocking=True)
+                        final_step = step + 1
+                        break
+                    step += 1
+                except recovery_lib.RollbackNeeded as rb:
+                    attempt = monitor.rollbacks + 1
+                    if attempt > recovery.max_rollbacks:
+                        raise FloatingPointError(
+                            f"divergence persists after "
+                            f"{recovery.max_rollbacks} rollbacks ({rb}); "
+                            f"last verified step {last_verified}"
+                        ) from rb
+                    monitor.rollbacks = attempt
+                    backoff = recovery.backoff_s(attempt)
+                    if backoff > 0:
+                        time.sleep(backoff)
+                    _drain_save_error()  # never race an in-flight save
+                    state, ck_step = _restore_latest(state)
+                    last_verified = ck_step
+                    monitor.start_step()  # a restore is no step's time
+                    if recovery.resample_on_rollback:
+                        # fold the attempt into the refresh RNG: stochastic
+                        # selection (sara/golore/grass) draws a DIFFERENT
+                        # subspace at the next refresh instead of replaying
+                        # the diverged one (dominant re-selects the same
+                        # subspace by construction -- see train/recovery.py)
+                        state = TrainState(
+                            state.params,
+                            recovery_lib.resample_opt_state(
+                                state.opt_state, attempt
+                            ),
+                        )
+                    # truncate host-side records to the rollback point
+                    if ck_step <= loss_base:
+                        losses.clear()
+                        loss_base = ck_step
+                    else:
+                        del losses[ck_step - loss_base:]
+                    history[:] = [
+                        r for r in history if r.get("step", -1.0) < ck_step
+                    ]
+                    pending.clear()
+                    detector.reset()
+                    monitor.bad_loss_count = 0
+                    history.append({
+                        "event": "rollback",
+                        "step": float(ck_step),
+                        "from_step": float(rb.step),
+                        "attempt": float(attempt),
+                        "reason": rb.reason,
+                    })
+                    step = ck_step
     finally:
         _flush_metrics(state, swallow_aborts=True)
         _drain_save_error()

@@ -3,10 +3,12 @@
 On a real multi-host pod these feed the coordination service; here they are
 host-local but fully functional (and unit-tested with a fake clock):
 
-  * ``StepMonitor``     -- per-step wall time EMA + median; flags steps slower
+  * ``StepMonitor``     -- per-step wall time + median, taken at each metric
+    fetch (a device sync) over the steps it completed; flags fetches slower
     than ``straggler_factor`` x median (straggler mitigation hook: the train
     loop logs and can re-shard/skip input hosts); NaN/Inf loss sentinel with
-    configurable tolerance before abort.
+    configurable tolerance before abort; backend compiles and their seconds
+    (one ``jax.monitoring`` listener per process).
   * ``HeartbeatRegistry`` -- worker liveness bookkeeping with stale-detection
     and an escalation edge: ``check(step)`` returns workers that *newly* went
     stale (re-arming when they come back), records the first-stale step per
@@ -30,6 +32,30 @@ import math
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# a jax.monitoring listener stays for the life of the process, so one
+# listener counts for every monitor, each from its own baseline
+_compiles = [0, 0.0]  # backend compiles of this process and their seconds
+_listening = False
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    if event == _BACKEND_COMPILE:
+        _compiles[0] += 1
+        _compiles[1] += duration_secs
+
+
+def compile_totals() -> Tuple[int, float]:
+    """(backend compiles, their seconds) of this process since the first
+    call, which registers the one listener."""
+    global _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    return _compiles[0], _compiles[1]
 
 
 class StepMonitor:
@@ -55,25 +81,33 @@ class StepMonitor:
         self.rollbacks = 0  # checkpoint rollbacks performed
         self.save_retries = 0  # checkpoint write attempts retried
         self.save_failures = 0  # saves abandoned after retries
+        self._compile_base = compile_totals()
 
     def start_step(self) -> None:
+        """Start the clock: the loop's start, or its resumption after a
+        rollback.  Each ``end_step`` restarts it."""
         self._t_start = self._clock()
 
     def end_step(
-        self, step: int, loss: Optional[float] = None
+        self, step: int, loss: Optional[float] = None, steps: int = 1
     ) -> Dict[str, float]:
-        """Close the step's wall-time window (straggler bookkeeping).
+        """Close a wall-time window at a device sync (straggler
+        bookkeeping): ``steps`` steps, the last ``step``, completed since
+        the previous call.  Dispatch is asynchronous, so only a sync ends
+        a step: the loop calls this at each metric fetch, and the time is
+        per step of that fetch.
 
         ``loss`` may be omitted when the caller defers the device->host
-        metric fetch (train/loop.py fetches at ``log_every`` cadence to
-        avoid a per-step sync) and feeds the NaN sentinel later via
-        ``note_loss`` -- the timing path never needs the loss value.
+        metric fetch and feeds the NaN sentinel via ``note_loss``.
         """
-        dt = self._clock() - (self._t_start or self._clock())
+        now = self._clock()
+        start = now if self._t_start is None else self._t_start
+        dt = (now - start) / max(steps, 1)
+        self._t_start = now
         self._times.append(dt)
         if len(self._times) > self.window:
             self._times.pop(0)
-        self.step_count += 1
+        self.step_count += steps
         med = sorted(self._times)[len(self._times) // 2]
         is_straggler = (
             len(self._times) >= 5 and dt > self.straggler_factor * med
@@ -115,12 +149,16 @@ class StepMonitor:
         return False
 
     def counters(self) -> Dict[str, float]:
-        """Recovery counters, merged into every history record."""
+        """Recovery counters and the backend compiles since the monitor
+        was made, merged into every history record."""
+        n, secs = compile_totals()
         return {
             "skip_steps": float(self.skip_steps),
             "rollbacks": float(self.rollbacks),
             "save_retries": float(self.save_retries),
             "save_failures": float(self.save_failures),
+            "compiles": float(n - self._compile_base[0]),
+            "compile_s": secs - self._compile_base[1],
         }
 
 

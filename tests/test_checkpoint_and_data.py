@@ -397,16 +397,22 @@ def test_straggler_detection():
     def clock():
         return t[0]
 
+    # the monitor times fetches (device syncs), each over the steps it
+    # completed: ten fetches of one 1 s step, one of four 1 s steps
     mon = StepMonitor(straggler_factor=3.0, clock=clock)
+    mon.start_step()
     for i in range(10):
-        mon.start_step()
         t[0] += 1.0
         mon.end_step(i, loss=1.0)
-    mon.start_step()
-    t[0] += 10.0  # 10x median
-    h = mon.end_step(10, loss=1.0)
+    t[0] += 4.0
+    h = mon.end_step(13, loss=1.0, steps=4)
+    assert h["step_time_s"] == 1.0 and h["straggler"] == 0.0
+    t[0] += 40.0  # four steps at 10x the median
+    h = mon.end_step(17, loss=1.0, steps=4)
+    assert h["step_time_s"] == 10.0 and h["median_step_time_s"] == 1.0
     assert h["straggler"] == 1.0
-    assert mon.stragglers == [10]
+    assert mon.stragglers == [17]
+    assert mon.step_count == 18
 
 
 def test_nan_sentinel_aborts():

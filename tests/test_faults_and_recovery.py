@@ -316,9 +316,12 @@ def test_slow_step_and_heartbeat(setup, tmp_path):
     # the loop beat every step; nobody is stale
     assert hb.stale() == []
     assert _last_rec(res)["stale_workers"] == 0.0
-    # the injected sleep shows up in the straggler stats
-    steps = [r for r in res.history if "step" in r and "event" not in r]
-    assert any(r["step"] == 3.0 for r in steps)
+    # the injected sleep shows up in the step time of the fetch that ends
+    # step 3 (log_every=1: one fetch per step), and in no other
+    steps = {r["step"]: r for r in res.history
+             if "step" in r and "event" not in r}
+    assert steps[3.0]["step_time_s"] >= 0.2
+    assert steps[2.0]["step_time_s"] < 0.2 and steps[5.0]["step_time_s"] < 0.2
 
 
 # ---------------------------------------------------------------------------

@@ -212,6 +212,12 @@ def test_subspace_tracking(setup, tmp_path):
     for name, vals in summary.items():
         if "adjacent_mean" in vals:
             assert 0.0 <= vals["adjacent_mean"] <= 1.0 + 1e-6
+    # the step's own reading, ||P_new^T P_old||^2 / r, in the history of
+    # the logged refresh steps (tau 10: steps 0 and 20) and of no other
+    recs = [r for r in res.history if "refresh_overlap" in r]
+    assert [r["step"] for r in recs] == [0.0, 20.0]
+    for r in recs:
+        assert 0.0 <= r["refresh_overlap"] <= 1.0 + 1e-6
 
 
 def test_serving_greedy_deterministic(setup):
