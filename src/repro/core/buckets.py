@@ -881,12 +881,13 @@ def bucketed_refresh(
     ``projectors.batched_refresh_supported`` covers the config): ALL of a
     bucket's same-group entries refresh as ONE batched chain over their
     stacked (B', d, n) gradients -- batched Gaussian sketch, fused power
-    iterations, batched thin QR, one small batched SVD, batched Gumbel
-    top-k -- instead of a chain per leaf.  Per-slice keys follow the exact
-    per-leaf schedule (``_entry_slice_keys``), so the batched stack is
-    bit-identical to the per-leaf fallback, which remains for the exact
-    backend (``stacked_refresh_fn=None``): slice each refreshed entry's
-    old projector out of the stack, run the per-leaf ``refresh_fn``, and
+    iterations, batched thin QR, one small batched ``eigh`` (the Gram
+    matrix of B = Q^T G), batched Gumbel top-k -- instead of a chain per
+    leaf.  Per-slice keys follow the exact per-leaf schedule
+    (``_entry_slice_keys``), so the batched stack is bit-identical to the
+    per-leaf fallback, which remains for the exact backend
+    (``stacked_refresh_fn=None``): slice each refreshed entry's old
+    projector out of the stack, run the per-leaf ``refresh_fn``, and
     concatenate the new slices back.
 
     Either way the scatter into the (B, d, r) stack is static, and the
@@ -1298,8 +1299,8 @@ def finite_check_model(
 
 def _refresh_chain_ops(engine: str, power_iters: int) -> int:
     """Dispatched ops of one chain: sketch draw + sketch GEMM + final QR +
-    B = Q^T G GEMM + small SVD + Gumbel sample + column gather (7), plus
-    per power iteration either QR + fused power step (batched, 2) or
+    B = Q^T G GEMM + Gram ``eigh`` + Gumbel sample + column gather (7),
+    plus per power iteration either QR + fused power step (batched, 2) or
     QR + Z GEMM + QR + Y GEMM (perleaf, 4).  ``power_iters`` is the
     post-clamp count -- callers apply ``svd.clamp_sketch`` per bucket so
     the gated numbers match what actually dispatches."""
@@ -1355,9 +1356,10 @@ def modeled_refresh_hbm_bytes(
     degenerate shapes clamped exactly like ``svd.clamp_sketch``): sketch
     GEMM, the power iterations (engine-dependent, see module comment --
     the batched engine's fused kernel deletes the 2 n k' Z round-trip and
-    one n-side QR per iteration), final QR, B = Q^T G, the small SVD,
-    U = Q U_b, and the sampled (d, r) projector write-back.  The batched
-    engine additionally pays the gradient concat for multi-entry buckets.
+    one n-side QR per iteration), final QR, B = Q^T G, the ``eigh`` of
+    its (k', k') Gram matrix, U = Q U_b, and the sampled (d, r) projector
+    write-back.  The batched engine additionally pays the gradient concat
+    for multi-entry buckets.
     """
     from repro.core import svd as svd_lib
 
@@ -1377,7 +1379,7 @@ def modeled_refresh_hbm_bytes(
                                   + 2 * nkp + (dn + nkp + dkp))
         per_slice += 2 * dkp  # final QR
         per_slice += dkp + dn + nkp  # B = Q^T G
-        per_slice += nkp + kp * kp + kp  # small SVD of B
+        per_slice += nkp + kp * kp + kp  # Gram of B and its eigh
         per_slice += 2 * dkp + kp * kp  # U = Q @ U_b
         per_slice += kp + d * r  # spectrum read + sampled projector write
         hot = [

@@ -211,14 +211,15 @@ def refresh_projector_stacked(
     The bucket-native refresh engine (core/buckets.bucketed_refresh) calls
     this once per bucket with every same-group leaf's slices stacked --
     batched Gaussian sketch, fused power iterations, batched thin QR, one
-    small batched SVD, batched Gumbel-top-k -- instead of a per-leaf chain
-    each.  ``keys`` is the (B,) per-slice key stack the caller derived with
-    the per-leaf schedule (fold the global leaf index, split over leading
-    dims), so every slice is bit-identical to what ``refresh_projector``
-    would produce for its leaf; only the dispatch shape changes.  ``prev_p``
-    is the (B, d, r) slice stack of the outgoing projectors (``online_pca``
-    consumes it; SVD methods ignore it).  Coverage is decided by
-    ``batched_refresh_supported`` -- callers must gate on it.
+    small batched Gram ``eigh``, batched Gumbel-top-k -- instead of a
+    per-leaf chain each.  ``keys`` is the (B,) per-slice key stack the
+    caller derived with the per-leaf schedule (fold the global leaf index,
+    split over leading dims), so every slice is bit-identical to what
+    ``refresh_projector`` would produce for its leaf; only the dispatch
+    shape changes.  ``prev_p`` is the (B, d, r) slice stack of the outgoing
+    projectors (``online_pca`` consumes it; SVD methods ignore it).
+    Coverage is decided by ``batched_refresh_supported`` -- callers must
+    gate on it.
 
     Returns a (B, d, rank) stack with orthonormal columns per slice.
     """

@@ -15,6 +15,16 @@ squares the sketch's spectrum exactly like the classical two-QR form; the
 dropped inner re-orthonormalization costs some stability for extreme
 spectra, which the thin QR between iterations bounds (documented
 deviation, traded for halving the QR count and fusing the GEMM pair).
+The last step needs only the left singular pairs of the wide ``B = Q^T G``
+(k' <= n by the clamp below), so it takes them from ``eigh`` of the
+(k', k') Gram matrix ``B B^T``: an SVD of ``B`` would pay for a
+Householder QR of its long side and for right vectors that are thrown
+away.  The Gram product runs at the default matmul precision, like ``B``'s
+own: on a TPU that rounds ``B``'s entries to bfloat16 and accumulates in
+float32, a perturbation of ``B`` the size of the one its own product
+already left, made before the spectrum is squared.  (At HIGHEST precision
+the TPU compiler emitted about 200 MB more program code for the refresh
+step, held in device memory, for no gain in the subspace.)
 
 Degenerate shapes are clamped rather than trusted to the caller: ``k`` is
 cut to ``min(m, n)`` (so the returned basis always has exactly the
@@ -81,8 +91,9 @@ def randomized_svd(
 ) -> Tuple[jax.Array, jax.Array]:
     """Randomized top-``k`` SVD (HMT 2011, fused subspace iteration).
 
-    Cost: ~(2 + 2q) GEMMs of (m,n)-by-(n,k') + (q+1) thin QRs + a small SVD
-    on (k', n), with k' = k + oversample.  All GEMMs partition cleanly under
+    Cost: ~(2 + 2q) GEMMs of (m,n)-by-(n,k') + (q+1) thin QRs + the
+    (k', k') Gram matrix of ``B = Q^T G`` and its ``eigh``, with
+    k' = k + oversample.  All GEMMs partition cleanly under
     SPMD when ``g`` is sharded, unlike a full dense SVD.  Single-slice entry
     point of the stacked chain below -- identical per-slice numerics.
     """
@@ -110,9 +121,10 @@ def randomized_svd_stacked(
     the per-leaf path would (fold the global leaf index, split over leading
     batch dims), so slice ``b`` draws the SAME Gaussian sketch it would have
     drawn per-leaf and the two paths stay bit-for-bit.  The whole stack runs
-    as batched GEMMs / thin QRs / one small batched SVD: the dispatched-op
-    count is per-chain, not per-leaf, and the power-iteration GEMM pair goes
-    through ``kernels/power_iter`` (VMEM-resident intermediate on TPU).
+    as batched GEMMs / thin QRs / one small batched ``eigh``: the
+    dispatched-op count is per-chain, not per-leaf, and the power-iteration
+    GEMM pair goes through ``kernels/power_iter`` (VMEM-resident
+    intermediate on TPU).
 
     Returns ``(U (B, m, k), S (B, k))``.
     """
@@ -133,8 +145,14 @@ def randomized_svd_stacked(
     with jax.named_scope("qr"):
         q, _ = jnp.linalg.qr(y)  # (B, m, kp) orthonormal range basis
     with jax.named_scope("small_svd"):
-        b = jnp.einsum("bmk,bmn->bkn", q, g)  # (B, kp, n) small
-        ub, s, _ = jnp.linalg.svd(b, full_matrices=False)
+        b = jnp.einsum("bmk,bmn->bkn", q, g)  # (B, kp, n), kp <= n
+        # The left singular pairs of the wide b are the eigenpairs of its
+        # (kp, kp) Gram matrix: no QR of the long side, no right vectors.
+        c = jnp.einsum("bkn,bjn->bkj", b, b)
+        with jax.default_matmul_precision("float32"):
+            w, v = jnp.linalg.eigh(c)  # ascending
+        ub = v[..., ::-1]
+        s = jnp.sqrt(jnp.maximum(w[..., ::-1], 0.0))
         u = jnp.einsum("bmk,bkj->bmj", q, ub)  # (B, m, kp)
         return u[..., :k], s[..., :k]
 

@@ -15,7 +15,11 @@ from repro.core.sampling import (
     gumbel_topk_indices_batched,
     inclusion_probabilities_mc,
 )
-from repro.core.svd import clamp_sketch, randomized_svd
+from repro.core.svd import (
+    clamp_sketch,
+    randomized_svd,
+    randomized_svd_stacked,
+)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -57,6 +61,56 @@ def test_randomized_svd_zero_gradient_stays_finite():
     u, s = randomized_svd(jnp.zeros((16, 32)), 4, KEY)
     assert np.isfinite(np.asarray(u)).all()
     assert np.allclose(np.asarray(s), 0.0)
+
+
+def _planted_stack(b, m, n, spectrum, seed=0):
+    """A (b, m, n) float64 stack with random singular vectors and the given
+    singular values."""
+    rng = np.random.default_rng(seed)
+    d = len(spectrum)
+    out = []
+    for _ in range(b):
+        u, _ = np.linalg.qr(rng.standard_normal((m, d)))
+        v, _ = np.linalg.qr(rng.standard_normal((n, d)))
+        out.append((u * spectrum) @ v.T)
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("decay", [0.5, 1.0])
+def test_randomized_svd_stacked_matches_float64_svd(decay):
+    """The small step (left singular pairs of the wide B = Q^T G from the
+    eigendecomposition of its Gram matrix) against float64 SVD of G.  The
+    planted spectrum i^-decay has rank k' = k + oversample, so the sketch
+    holds G's whole range and any error left is the small step's."""
+    b, m, n, k, oversample = 3, 96, 400, 40, 8
+    spectrum = np.zeros(min(m, n))
+    spectrum[: k + oversample] = np.arange(1.0, k + oversample + 1) ** -decay
+    g = _planted_stack(b, m, n, spectrum)
+    keys = jax.random.split(jax.random.fold_in(KEY, 5), b)
+    u, s = randomized_svd_stacked(
+        jnp.asarray(g, jnp.float32), k, keys, oversample=oversample
+    )
+    u, s = np.asarray(u, np.float64), np.asarray(s, np.float64)
+    assert u.shape == (b, m, k) and s.shape == (b, k)
+    u_ref, s_ref, _ = np.linalg.svd(g, full_matrices=False)
+    for i in range(b):
+        np.testing.assert_allclose(u[i].T @ u[i], np.eye(k), atol=1e-5)
+        assert (np.diff(s[i]) <= 0).all()  # descending, as SARA indexes
+        np.testing.assert_allclose(s[i], s_ref[i, :k], rtol=1e-3)
+        overlap = np.sum((u_ref[i, :, :k].T @ u[i]) ** 2) / k
+        assert overlap >= 1 - 1e-4, overlap
+
+
+def test_randomized_svd_stacked_tiny_gradient_stays_finite():
+    """A gradient of scale 1e-20: its Gram matrix underflows float32, and
+    the basis must still come back finite and orthonormal."""
+    g = jax.random.normal(KEY, (3, 96, 400)) * 1e-20
+    u, s = randomized_svd_stacked(g, 40, jax.random.split(KEY, 3))
+    u, s = np.asarray(u), np.asarray(s)
+    assert np.isfinite(u).all() and np.isfinite(s).all()
+    assert (s >= 0).all()
+    for i in range(3):
+        np.testing.assert_allclose(u[i].T @ u[i], np.eye(40), atol=1e-5)
 
 
 def test_stacked_refresh_matches_per_slice():
