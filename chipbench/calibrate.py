@@ -61,7 +61,7 @@ def readings(workload: str, seeds, variants, rehearsal: bool = False,
         key = weights_lib.make_key(words[:2])
         opt_seed = words[2] & 0x7FFFFFFF
         traffic = traffic_lib.Traffic(
-            cell.traffic, vocab=cell.config["vocab_size"], batch=cell.batch,
+            cell.traffic, vocab=cell.vocab, batch=cell.batch,
             seq_len=cell.seq_len, seed=seed)
         steps = range(cell.check_steps)
         full = [traffic.batch_at(s) for s in steps]

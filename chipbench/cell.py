@@ -1,8 +1,6 @@
 """Finds a cell's files by name: its entry in BENCHMARK.json, its workload
-file (traffic parameters and correctness limits) and its configuration file.
-
-Nothing here imports JAX or the program, so the harness can resolve and
-validate a cell before it touches the chip.
+file (traffic parameters and correctness limits), its configuration file
+and the configuration's family module (``chipbench.families``).
 """
 from __future__ import annotations
 
@@ -11,24 +9,10 @@ import json
 from pathlib import Path
 from typing import Any, Dict, List, Tuple
 
+from chipbench import families
+
 BENCH = Path(__file__).resolve().parent  # chipbench/
 CHECKOUT = BENCH.parent
-
-# the configuration file's sizes and the ModelConfig field each one sets
-MODEL_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "num_hidden_layers": "n_layers",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_eps",
-    "qkv_bias": "qkv_bias",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
 
 def _load(path: Path) -> Dict[str, Any]:
     with open(path) as f:
@@ -48,6 +32,10 @@ class Cell:
     @property
     def seq_len(self) -> int:
         return int(self.config.get("seq_len", self.traffic["seq_len"]))
+
+    @property
+    def vocab(self) -> int:
+        return int(families.load(self.config).vocab(self.config))
 
     @property
     def batch(self) -> int:
@@ -89,6 +77,7 @@ def load_cell(name: str, *, rehearsal: bool = False) -> Cell:
             f"BENCHMARK.json {entry['config']!r}"
         )
     config = _load(CHECKOUT / configs[entry["config"]]["file"])
+    families.load(config)  # an unknown family fails before the chip is touched
     if rehearsal:
         config = {**config, **config["rehearsal"]}
     return Cell(
@@ -108,7 +97,8 @@ def model_config(cell: Cell):
     from repro.configs.registry import get_config
 
     base = get_config(cell.config["registry"])
-    kw = {field: cell.config[key] for key, field in MODEL_KEYS.items()}
+    keys = families.load(cell.config).KEYS
+    kw = {field: cell.config[key] for key, field in keys.items()}
     if cell.rehearsal:
         kw.update(loss_chunk=32, attn_chunk_q=16, attn_chunk_kv=16)
     return base.with_(**kw)

@@ -6,7 +6,7 @@ The numbers, each compared where the workload file gives it a limit:
 * ``loss_gap``: the largest relative gap of a step's loss, over the first
   ``check_steps`` steps (steps 0 and on run the update of the step before).
 * ``grad_gap``: the first gradient as the optimizer got it (the first
-  moment after one step over (1 - b1)), per layer of each leaf: the gap
+  moment after one step over (1 - b1)), per slice of each leaf: the gap
   between the program's norm and the reference's, over the reference's
   norm of that leaf or of the median leaf, whichever is larger; the worst.
 * ``second_grad_gap``: the same for the second step's gradient as the

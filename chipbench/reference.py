@@ -1,14 +1,14 @@
 """Plain reference of the cells' training step, written from the published
 descriptions and imported from nothing of the program.
 
-* The model: a dense pre-norm decoder (RMSNorm, grouped-query attention
-  with rotary embeddings and causal masking, SwiGLU MLP, untied or tied
-  head), its mean next-token loss and its gradients, all in float32 with
-  every matrix product at ``Precision.HIGHEST``.  It runs layer by layer
-  (``jax.checkpoint`` per layer, attention in blocks of queries, the loss
-  in blocks of positions) so that it fits next to its own optimizer state.
+* The model: the configuration's family (``chipbench.families``) gives
+  the forward and its mean next-token loss; here its gradients are taken,
+  in float32 with every matrix product at ``Precision.HIGHEST`` (``mm``
+  and ``ein``, which the families' forwards call).
 * The optimizer: GaLore with SARA subspace selection (Algorithm 1 and 2 of
-  the paper) around Adam.  Each projection matrix is oriented so that its
+  the paper) around Adam.  A low-rank leaf is a stack of matrices (the
+  family says which leaves, and how many leading axes stack them, taken
+  row-major as one slice axis); each matrix is oriented so that its
   smaller side ``d`` is projected; a refresh takes a randomized SVD of the
   gradient (a Gaussian sketch of width ``k + oversample`` with
   ``k = pool x r``, subspace iterations with a QR between them, the small
@@ -22,8 +22,8 @@ descriptions and imported from nothing of the program.
 * SARA's draw is seeded.  The keys follow the schedule the configuration
   states: the optimizer's key starts as ``PRNGKey(seed)`` and is split at
   each refresh; leaf ``i`` of the flattened tree folds ``i`` into the
-  refresh key, and a stack of ``L`` layers splits that over its layers;
-  each layer splits its key into the sketch's and the sample's.
+  refresh key, and a stack of ``S`` matrices splits that over its slices;
+  each slice splits its key into the sketch's and the sample's.
 
 ``prec`` rounds the inputs of every model matrix product to a lower
 precision in the forward pass: "fp8" is the control of the correctness
@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import families
 from chipbench import weights as weights_lib
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -52,9 +53,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 # TPU's default matmul precision)
 ROUND = {"f32": (None, False, False), "bf16": (jnp.bfloat16, True, True),
          "fp8": (jnp.float8_e4m3fn, False, False)}
-ATTN_BLOCK = 512  # queries per attention block
-LOSS_BLOCK = 1024  # positions per loss block
-EIGH_THREADS = 8  # host threads for the layers' small eigendecompositions
+EIGH_THREADS = 8  # host threads for the slices' small eigendecompositions
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -89,95 +88,6 @@ def ein(spec, a, b, prec="f32"):
 
 
 # ---------------------------------------------------------------------------
-# the model
-# ---------------------------------------------------------------------------
-
-
-def rmsnorm(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def rope(x, theta):
-    """Rotary embedding on (B, S, heads, hd): the two halves of each head
-    rotate by position x theta^(-2i/hd)."""
-    s, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(q, k, v, prec):
-    """Causal grouped-query attention.  q (B, S, H, hd); k, v (B, S, KVH,
-    hd); query head h reads key/value head h // (H / KVH)."""
-    b, s, h, hd = q.shape
-    kvh = k.shape[2]
-    q = q.reshape(b, s, kvh, h // kvh, hd)
-    blk = min(ATTN_BLOCK, s)
-
-    @jax.checkpoint
-    def block(qb, kb, vb, start):
-        sc = ein("bqkgd,bskd->bkgqs", qb, kb, prec) / math.sqrt(hd)
-        qpos = start + jnp.arange(qb.shape[1])
-        mask = qpos[:, None] >= jnp.arange(kb.shape[1])[None, :]
-        sc = jnp.where(mask, sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return ein("bkgqs,bskd->bqkgd", p, vb, prec)
-
-    outs = []
-    for start in range(0, s, blk):
-        end = min(start + blk, s)
-        outs.append(block(q[:, start:end], k[:, :end], v[:, :end], start))
-    return jnp.concatenate(outs, 1).reshape(b, s, h * hd)
-
-
-def layer(x, p, c, prec):
-    b, s, _ = x.shape
-    h = rmsnorm(x, p["attn_norm"], c["eps"])
-    q, k, v = (mm(h, p[n], prec) for n in ("q_proj", "k_proj", "v_proj"))
-    if "q_bias" in p:
-        q, k, v = q + p["q_bias"], k + p["k_bias"], v + p["v_bias"]
-    q = rope(q.reshape(b, s, c["H"], c["hd"]), c["theta"])
-    k = rope(k.reshape(b, s, c["KVH"], c["hd"]), c["theta"])
-    v = v.reshape(b, s, c["KVH"], c["hd"])
-    x = x + mm(attention(q, k, v, prec), p["o_proj"], prec)
-    h = rmsnorm(x, p["mlp_norm"], c["eps"])
-    mlp = p["mlp"]
-    up = jax.nn.silu(mm(h, mlp["gate_proj"], prec)) * mm(h, mlp["up_proj"], prec)
-    return x + mm(up, mlp["down_proj"], prec)
-
-
-def loss(params, tokens, labels, c, prec="f32"):
-    """Mean next-token NLL over the positions whose label is >= 0."""
-    x = params["embed"][tokens]
-
-    def body(x, p):
-        return jax.checkpoint(lambda x_, p_: layer(x_, p_, c, prec))(x, p), None
-
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = rmsnorm(x, params["final_norm"], c["eps"])
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-
-    @jax.checkpoint
-    def nll(xc, yc):
-        logits = mm(xc, head, prec)
-        lz = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, jnp.maximum(yc, 0)[..., None], axis=-1)[..., 0]
-        keep = (yc >= 0).astype(jnp.float32)
-        return jnp.sum((lz - picked) * keep), jnp.sum(keep)
-
-    s = tokens.shape[1]
-    total = count = 0.0
-    for start in range(0, s, LOSS_BLOCK):
-        t, n = nll(x[:, start:start + LOSS_BLOCK],
-                   labels[:, start:start + LOSS_BLOCK])
-        total, count = total + t, count + n
-    return total / jnp.maximum(count, 1.0)
-
-
-# ---------------------------------------------------------------------------
 # the optimizer
 # ---------------------------------------------------------------------------
 
@@ -185,13 +95,14 @@ def loss(params, tokens, labels, c, prec="f32"):
 class OptState(NamedTuple):
     step: jax.Array
     key: jax.Array
-    proj: Tuple  # per leaf: (L, d, r) projector, or None (full rank)
-    m: Tuple  # per leaf: first moment ((L, r, n') oriented, or leaf-shaped)
+    proj: Tuple  # per leaf: (S, d, r) projector, or None (full rank)
+    m: Tuple  # per leaf: first moment ((S, r, n') oriented, or leaf-shaped)
     v: Tuple
 
 
-def is_lowrank(path: str) -> bool:
-    return "_proj" in path
+def _slices(x):
+    """A low-rank leaf as one stack of S matrices, (S, rows, cols)."""
+    return x.reshape((-1,) + x.shape[-2:])
 
 
 def _left(shape) -> bool:
@@ -202,19 +113,18 @@ def _orient(x, left: bool):
     return x if left else jnp.swapaxes(x, -1, -2)
 
 
-def opt_init(params, oc, seed: int) -> OptState:
+def opt_init(params, oc, seed: int, layout) -> OptState:
     if oc["momentum_carry"] != "keep":
         raise ValueError(f"momentum_carry {oc['momentum_carry']!r}: the "
                          "reference keeps the moments at a refresh")
-    flat, _ = jax.tree_util.tree_flatten_with_path(params)
     proj, m, v = [], [], []
-    for path, x in flat:
-        if is_lowrank(jax.tree_util.keystr(path)):
+    for leaf, x in zip(layout, jax.tree_util.tree_leaves(params)):
+        if leaf.lowrank:
             left = _left(x.shape)
             d, n = (x.shape[-2], x.shape[-1]) if left else (
                 x.shape[-1], x.shape[-2])
             r = min(oc["rank"], d)
-            lead = x.shape[:-2]
+            lead = (weights_lib.slices(x.shape),)
             proj.append(jnp.broadcast_to(jnp.eye(d, r, dtype=jnp.float32),
                                          lead + (d, r)))
             m.append(jnp.zeros(lead + (r, n), jnp.float32))
@@ -285,20 +195,20 @@ def clip(grads, max_norm: float):
     return jax.tree_util.tree_map(lambda g: g * scale, grads)
 
 
-def opt_step(params, state: OptState, grads, oc, new_proj=None):
+def opt_step(params, state: OptState, grads, oc, new_bases=None):
     """One Adam step in each leaf's subspace on clipped ``grads`` (as
-    ``clip`` gives them); ``new_proj`` (one projector stack per low-rank
+    ``clip`` gives them); ``new_bases`` (one projector stack per low-rank
     leaf, None elsewhere) makes it a refresh step."""
     step = state.step + 1
     t = step.astype(jnp.float32)
     b1, b2, eps = oc["b1"], oc["b2"], oc["eps"]
     lr = learning_rate(state.step, oc)
     key = state.key
-    if new_proj is not None:
+    if new_bases is not None:
         key, _ = jax.random.split(key)
     flat_p, treedef = jax.tree_util.tree_flatten(params)
     flat_g = jax.tree_util.tree_leaves(grads)
-    out_p, out_proj, out_m, out_v = [], [], [], []
+    out_p, out_bases, out_m, out_v = [], [], [], []
 
     def adam(m, v, r):
         m = b1 * m + (1 - b1) * r
@@ -312,17 +222,18 @@ def opt_step(params, state: OptState, grads, oc, new_proj=None):
             out_p.append(w - lr * n)
         else:
             left = _left(w.shape)
-            go = _orient(g, left)
-            if new_proj is not None:
-                p = new_proj[i]
+            go = _orient(_slices(g), left)
+            if new_bases is not None:
+                p = new_bases[i]
             m, v, n = adam(m, v, ein("ldr,ldn->lrn", p, go))
-            wo = _orient(w, left) - lr * oc["alpha"] * ein("ldr,lrn->ldn", p, n)
-            out_p.append(_orient(wo, left))
-        out_proj.append(p)
+            wo = (_orient(_slices(w), left)
+                  - lr * oc["alpha"] * ein("ldr,lrn->ldn", p, n))
+            out_p.append(_orient(wo, left).reshape(w.shape))
+        out_bases.append(p)
         out_m.append(m)
         out_v.append(v)
     return (jax.tree_util.tree_unflatten(treedef, out_p),
-            OptState(step, key, tuple(out_proj), tuple(out_m), tuple(out_v)))
+            OptState(step, key, tuple(out_bases), tuple(out_m), tuple(out_v)))
 
 
 # ---------------------------------------------------------------------------
@@ -330,28 +241,31 @@ def opt_step(params, state: OptState, grads, oc, new_proj=None):
 # ---------------------------------------------------------------------------
 
 
-def slice_norms(x: jax.Array, stacked: bool) -> jax.Array:
-    """Frobenius norm of each layer of a stacked leaf, or of the leaf."""
+def slice_norms(x: jax.Array, stack: int) -> jax.Array:
+    """Frobenius norm of each matrix of a leaf whose first ``stack`` axes
+    stack them (row-major), or of the leaf where ``stack`` is 0."""
     x = x.astype(jnp.float32)
-    if stacked:
-        return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
+    if stack:
+        return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(stack, x.ndim)))
+                        ).reshape(-1)
     return jnp.sqrt(jnp.sum(x * x))[None]
 
 
-def slice_sums(x: jax.Array, stacked: bool) -> jax.Array:
-    """Sum of each layer of a stacked leaf, or of the leaf."""
+def slice_sums(x: jax.Array, stack: int) -> jax.Array:
+    """Sum of each matrix of a stack, as ``slice_norms`` takes them."""
     x = x.astype(jnp.float32)
-    if stacked:
-        return jnp.sum(x, axis=tuple(range(1, x.ndim)))
+    if stack:
+        return jnp.sum(x, axis=tuple(range(stack, x.ndim))).reshape(-1)
     return jnp.sum(x)[None]
 
 
-def moment_readings(ms, vs, stacked, b1: float):
-    """Per layer of each leaf: the first moment's norm over (1 - b1), which
+def moment_readings(ms, vs, stacks, b1: float):
+    """Per slice of each leaf: the first moment's norm over (1 - b1), which
     after one step is the norm of the gradient as the optimizer got it
-    (projected, for a low-rank leaf), and the second moment's sum."""
-    return ([slice_norms(m, s) / (1.0 - b1) for m, s in zip(ms, stacked)],
-            [slice_sums(v, s) for v, s in zip(vs, stacked)])
+    (projected, for a low-rank leaf), and the second moment's sum;
+    ``stacks`` gives each moment's stack axes."""
+    return ([slice_norms(m, s) / (1.0 - b1) for m, s in zip(ms, stacks)],
+            [slice_sums(v, s) for v, s in zip(vs, stacks)])
 
 
 def second_grad_norms(v0_sums, v1_sums, b2: float) -> List[np.ndarray]:
@@ -365,21 +279,14 @@ def second_grad_norms(v0_sums, v1_sums, b2: float) -> List[np.ndarray]:
 
 
 def named_norms(layout, arrays) -> Dict[str, float]:
-    """{"<path>[<layer>]": norm} from per-leaf norm vectors."""
+    """{"<path>[<slice>]": norm} (``<path>`` alone for a leaf that is no
+    stack) from per-leaf norm vectors."""
     out = {}
-    for (path, _), vals in zip(layout, arrays):
+    for leaf, vals in zip(layout, arrays):
         vals = np.asarray(vals)
-        for li, val in enumerate(vals):
-            name = f"{path}[{li}]" if path.startswith("['blocks']") else path
-            out[name] = float(val)
+        for i, val in enumerate(vals):
+            out[f"{leaf.path}[{i}]" if leaf.stack else leaf.path] = float(val)
     return out
-
-
-def model_constants(config: Dict[str, Any]) -> Dict[str, Any]:
-    d = weights_lib.dims(config)
-    return dict(H=d["H"], KVH=d["KVH"], hd=d["hd"],
-                theta=float(config["rope_theta"]),
-                eps=float(config["rms_norm_eps"]))
 
 
 def optimizer_constants(config: Dict[str, Any]) -> Dict[str, Any]:
@@ -403,21 +310,23 @@ class Reference:
         self.redraw = redraw
         self.signs = np.random.default_rng(1) if signs else None
         self.layout = weights_lib.leaves(config)
-        c = model_constants(config)
+        fam = families.load(config)
+        c = fam.constants(config)
         oc = optimizer_constants(config)
         self.oc = oc
-        stacked = [p.startswith("['blocks']") for p, _ in self.layout]
+        # the moments of a low-rank leaf are one stack of slices
+        stacks = [1 if x.lowrank else x.stack for x in self.layout]
 
         def vg(params, tokens, labels):
             with jax.default_matmul_precision("highest"):
-                return jax.value_and_grad(loss)(params, tokens, labels, c,
-                                                prec)
+                return jax.value_and_grad(fam.loss)(params, tokens, labels, c,
+                                                    prec)
 
         self._vg = jax.jit(vg)
 
-        def step(params, state, grads, new_proj):
+        def step(params, state, grads, new_bases):
             with jax.default_matmul_precision("highest"):
-                return opt_step(params, state, grads, oc, new_proj)
+                return opt_step(params, state, grads, oc, new_bases)
 
         self._step = jax.jit(step, donate_argnums=(0, 1, 2))
         self._clip = jax.jit(functools.partial(clip,
@@ -442,7 +351,7 @@ class Reference:
         self._select = select_leaf
 
         self._moments = jax.jit(lambda state: moment_readings(
-            state.m, state.v, stacked, oc["b1"]))
+            state.m, state.v, stacks, oc["b1"]))
 
     def refresh(self, grads, state: OptState):
         """New projectors of every low-rank leaf from this step's grads."""
@@ -457,6 +366,7 @@ class Reference:
                 continue
             d, r = p.shape[-2:]
             k = min(self.oc["sara_pool_factor"] * r, d)
+            g = _slices(g)
             keys = jax.random.split(jax.random.fold_in(sub, i), g.shape[0])
             q, gram, k_sample = self._sketch(g, g.shape[-2] <= g.shape[-1],
                                              keys, r)
@@ -473,7 +383,7 @@ class Reference:
     def run(self, key, opt_seed: int, batches: List[Dict[str, np.ndarray]],
             tau: int) -> Dict[str, Any]:
         params = weights_lib.init(key, self.config)
-        state = opt_init(params, self.oc, opt_seed)
+        state = opt_init(params, self.oc, opt_seed, self.layout)
         if len(batches) < 2:
             raise ValueError("the check reads the first two steps")
         losses, readings = [], []
@@ -482,8 +392,8 @@ class Reference:
                                    jnp.asarray(batch["labels"]))
             grads = self._clip(grads)
             losses.append(float(lval))
-            new_proj = self.refresh(grads, state) if s % tau == 0 else None
-            params, state = self._step(params, state, grads, new_proj)
+            new_bases = self.refresh(grads, state) if s % tau == 0 else None
+            params, state = self._step(params, state, grads, new_bases)
             if s < 2:
                 readings.append(jax.device_get(self._moments(state)))
         del state
@@ -497,21 +407,19 @@ class Reference:
 
 
 @functools.lru_cache(maxsize=None)
-def _change_fn(index: int, path: str, shape: Tuple[int, ...], stacked: bool):
+def _change_fn(index: int, shape: Tuple[int, ...], ones: bool, stack: int):
     def f(key, w):
-        return slice_norms(w - weights_lib.leaf_value(key, index, path, shape),
-                           stacked)
+        return slice_norms(w - weights_lib.leaf_value(key, index, shape, ones),
+                           stack)
 
     return jax.jit(f)
 
 
 def change_norms(key, config, params) -> Dict[str, float]:
-    """Per layer, the norm of the change of the weights from the seed's:
+    """Per slice, the norm of the change of the weights from the seed's:
     each leaf's initial value is made again on its own, one at a time."""
     layout = weights_lib.leaves(config)
     flat = jax.tree_util.tree_leaves(params)
-    vals = [
-        _change_fn(i, p, s, p.startswith("['blocks']"))(key, w)
-        for i, ((p, s), w) in enumerate(zip(layout, flat))
-    ]
+    vals = [_change_fn(i, x.shape, x.ones, x.stack)(key, w)
+            for i, (x, w) in enumerate(zip(layout, flat))]
     return named_norms(layout, vals)

@@ -242,7 +242,7 @@ def build_job(cell, seed: int) -> Job:
                      seed=opt_seed)
     fns = make_train_step(model, opt, train_cfg=tc)
     traffic = traffic_lib.Traffic(
-        cell.traffic, vocab=cell.config["vocab_size"], batch=cell.batch,
+        cell.traffic, vocab=cell.vocab, batch=cell.batch,
         seq_len=cell.seq_len, seed=seed)
     return Job(model, opt, fns, tc, state, traffic, key, opt_seed)
 
@@ -300,7 +300,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     model, opt, fns, tc, state, traffic, key, opt_seed = build_job(cell, seed)
     t_built = time.perf_counter()
     layout = weights_lib.leaves(cell.config)
-    stacked = [p.startswith("['blocks']") for p, _ in layout]
+    stacks = [x.stack for x in layout]
     b1, b2 = opt.config.b1, opt.config.b2
 
     @jax.jit
@@ -310,7 +310,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             canon.leaves, is_leaf=lambda x: isinstance(x, LeafState))
         return ref_lib.moment_readings([ls.inner.m for ls in leaf_states],
                                        [ls.inner.v for ls in leaf_states],
-                                       stacked, b1)
+                                       stacks, b1)
 
     prog: Dict[str, Any] = {}
     after: Dict[int, Any] = {}  # step: moment readings of the state after

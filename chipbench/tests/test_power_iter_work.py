@@ -11,8 +11,8 @@ OPT = {"name": "galore-sara-adam", "sara_pool_factor": 2,
 
 
 def test_power_iter_at_a_tiny_config():
-    config = dict(num_hidden_layers=1, hidden_size=8, intermediate_size=16,
-                  num_attention_heads=2, num_key_value_heads=1, head_dim=4,
+    config = dict(family="dense", num_hidden_layers=1, hidden_size=8,
+                  intermediate_size=16, num_attention_heads=2, num_key_value_heads=1, head_dim=4,
                   vocab_size=32, qkv_bias=False, tie_word_embeddings=False,
                   optimizer=OPT)
     # rank 2, pool 4, sketch 5: q, o (8, 8) and the MLP (8, 16) iterate

@@ -1,13 +1,15 @@
 """The benchmark's work functions against counts written out by hand."""
 import pytest
 
-from chipbench.work import flash_attention, model_flops, optimizer_update
+from chipbench.families import dense
+from chipbench.work import flash_attention, optimizer_update
 
 
 def _config(layers, d, f, h, kvh, hd, v, **kw):
-    return dict(num_hidden_layers=layers, hidden_size=d, intermediate_size=f,
-                num_attention_heads=h, num_key_value_heads=kvh, head_dim=hd,
-                vocab_size=v, qkv_bias=False, tie_word_embeddings=False, **kw)
+    return dict(family="dense", num_hidden_layers=layers, hidden_size=d,
+                intermediate_size=f, num_attention_heads=h,
+                num_key_value_heads=kvh, head_dim=hd, vocab_size=v,
+                qkv_bias=False, tie_word_embeddings=False, **kw)
 
 
 # published widths, full depth
@@ -26,7 +28,7 @@ GRANITE_8B = _config(36, 4096, 14336, 32, 8, 128, 49152)
      + 4096 * 49152),
 ])
 def test_matrix_params(config, want):
-    assert model_flops.matrix_params(config) == want
+    assert dense.matrix_params(config) == want
 
 
 @pytest.mark.parametrize("config, want", [
@@ -36,7 +38,7 @@ def test_matrix_params(config, want):
     (GRANITE_8B, 6 * 8_053_063_680 + 6 * 36 * 4096 * 4096),
 ])
 def test_flops_per_token(config, want):
-    assert model_flops.flops_per_token(config, 4096) == want
+    assert dense.flops_per_token(config, 4096) == want
     assert want in (10_318_381_056, 51_942_260_736)
 
 

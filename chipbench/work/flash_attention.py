@@ -5,7 +5,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
-from chipbench import weights as weights_lib
+from chipbench import families
 
 BF16 = 2
 
@@ -13,8 +13,7 @@ BF16 = 2
 def per_call(config: Dict[str, Any], batch: int, seq_len: int
              ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one layer's attention forward."""
-    d = weights_lib.dims(config)
-    h, kvh, hd = d["H"], d["KVH"], d["hd"]
+    h, kvh, hd = families.load(config).attention_heads(config)
     flops = 2 * 2.0 * batch * h * seq_len * seq_len * hd / 2
     bytes_ = BF16 * batch * seq_len * hd * (2 * h + 2 * kvh)
     return flops, bytes_

@@ -1,6 +1,6 @@
 """The low-rank update of one hot step, both kernels together, summed over
-every projection matrix of the configuration: R = P^T G and the
-back-projection P N (two d x r x n products per layer slice), reading W, G,
+every low-rank leaf of the configuration: R = P^T G and the
+back-projection P N (two d x r x n products per slice), reading W, G,
 P and the two moments once and writing W' and the moments once, all in
 float32.  Each matrix is oriented so its smaller side d is projected, at
 rank min(r, d)."""
@@ -14,18 +14,15 @@ F32 = 4
 
 
 def slices(config: Dict[str, Any], rank: int) -> List[Tuple[int, int, int, int]]:
-    """(count, d, n, r) of the projected matrices: count layer slices of
+    """(count, d, n, r) of the projected matrices: count slices of
     oriented shape (d, n) at rank r."""
     out = []
-    for path, shape in weights_lib.leaves(config):
-        if "_proj" not in path:
+    for leaf in weights_lib.leaves(config):
+        if not leaf.lowrank:
             continue
-        m, n = shape[-2:]
+        m, n = leaf.shape[-2:]
         d, n = min(m, n), max(m, n)
-        count = 1
-        for s in shape[:-2]:
-            count *= s
-        out.append((count, d, n, min(rank, d)))
+        out.append((weights_lib.slices(leaf.shape), d, n, min(rank, d)))
     return out
 
 
